@@ -5,7 +5,11 @@ paged-vs-contiguous admission cost, chunked-prefill decode
 interference, fused decode horizons, and priority-mix QoS under page
 pressure.
 
-    PYTHONPATH=src python -m benchmarks.serve_bench [--fast]
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+        PYTHONPATH=src python -m benchmarks.serve_bench [--fast]
+
+(part 7's mesh sweep serves at mp 1, 2 and 4 in this one process and
+needs at least four devices; on the CPU the flag above provides them)
 
 Part 10 — fault-storm sweep (what PR 10's recovery ladder buys): the
 same mixed interactive/batch workload served twice on identically
@@ -130,7 +134,6 @@ from __future__ import annotations
 import copy
 import json
 import os
-import subprocess
 import sys
 import time
 from typing import List
@@ -140,6 +143,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import VPE
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model
 from repro.runtime.serve_faults import FaultPlan, FaultSpec
 from repro.runtime.serve_loop import (
@@ -748,21 +752,22 @@ def bench_priority_mix(cfg, params) -> bool:
     return ok
 
 
-# shard sweep: same decode workload served at mp in {1, 2, 4} on forced
-# host devices.  XLA_FLAGS must be set before jax initializes, and this
-# module imports jax at the top — so each mesh width runs in a child
-# process and the parent only aggregates.  Forced host "devices" share
-# one CPU, so mp>1 cannot be faster here; the sweep pins token-exact
-# parity and leak-free drain per width (the dispatch keys carry the
-# shard bucket, so tok/s per width is still a real measured point).
+# shard sweep: same decode workload served at mp in {1, 2, 4} over
+# jax.devices(), all in this process — a chip belongs to one process, so
+# children started after this process has touched JAX could never get
+# one.  On the CPU the caller provides the devices before JAX starts:
+# XLA_FLAGS=--xla_force_host_platform_device_count=8.  Forced host
+# "devices" share one CPU, so mp>1 cannot be faster there; the sweep pins
+# token-exact parity and leak-free drain per width (the dispatch keys
+# carry the shard bucket, so tok/s per width is still a real measured
+# point).
 SHARD_MPS = (1, 2, 4)
 SHARD_REQS = 12
-SHARD_SENTINEL = "SHARD_RESULT "
 
 
 def _shard_workload(vocab: int) -> List[Request]:
-    """Decode-bound and deterministic: identical across child processes
-    so outputs are comparable token-for-token."""
+    """Decode-bound and deterministic: identical for every mesh width so
+    outputs are comparable token-for-token."""
     rng = np.random.default_rng(11)
     return [Request(rid=i,
                     prompt=rng.integers(0, vocab, 16).astype(np.int32),
@@ -770,18 +775,15 @@ def _shard_workload(vocab: int) -> List[Request]:
             for i in range(SHARD_REQS)]
 
 
-def _shard_child(mp: int) -> None:
-    """Runs in a subprocess with forced host devices: serve the fixed
-    workload at mesh (1, mp) and print one sentinel-prefixed JSON line."""
-    cfg = get_config("qwen3-8b").reduced()
-    params = model.init_params(cfg, jax.random.PRNGKey(0))
+def _shard_point(cfg, params, mp: int) -> dict:
+    """Serve the fixed workload at mesh (1, mp): one warm pass, one timed."""
     eng = make_serve_engine(cfg, params, mesh_shape=(1, mp), slots=SLOTS,
                             max_len=MAX_LEN, kv_layout="paged", block_size=16)
     run_engine(eng, _shard_workload(cfg.vocab_size))   # warm: compiles
     eng.stats = type(eng.stats)()
     r = run_engine(eng, _shard_workload(cfg.vocab_size))
     eng.check_kv()   # raises on any leaked page / dangling reference
-    result = {
+    return {
         "mp": mp,
         "devices": jax.device_count(),
         "kv_heads_sharded": cfg.num_kv_heads % mp == 0,
@@ -790,27 +792,17 @@ def _shard_child(mp: int) -> None:
         "kv_clean_at_drain": True,
         "outs": {str(k): v for k, v in r["outs"].items()},
     }
-    print(SHARD_SENTINEL + json.dumps(result, sort_keys=True))
 
 
-def bench_shard_sweep() -> bool:
-    """mp sweep in subprocesses; parity vs mp=1 and leak-free drain."""
-    results = {}
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                        + env.get("XLA_FLAGS", "")).strip()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for mp in SHARD_MPS:
-        proc = subprocess.run(
-            [sys.executable, os.path.abspath(__file__),
-             "--shard-child", str(mp)],
-            capture_output=True, text=True, env=env, cwd=repo)
-        lines = [ln for ln in proc.stdout.splitlines()
-                 if ln.startswith(SHARD_SENTINEL)]
-        if proc.returncode != 0 or not lines:
-            print(f"# shard mp={mp} child FAILED:\n{proc.stderr[-2000:]}")
-            return False
-        results[str(mp)] = json.loads(lines[-1][len(SHARD_SENTINEL):])
+def bench_shard_sweep(cfg, params) -> bool:
+    """mp sweep in this process; parity vs mp=1 and leak-free drain."""
+    need = max(SHARD_MPS)
+    if jax.device_count() < need:
+        print(f"# shard sweep: FAIL (needs {need} devices, "
+              f"{jax.device_count()} visible; on the CPU run with "
+              f"XLA_FLAGS=--xla_force_host_platform_device_count=8)")
+        return False
+    results = {str(mp): _shard_point(cfg, params, mp) for mp in SHARD_MPS}
     outs = {k: v.pop("outs") for k, v in results.items()}
     parity = all(o == outs["1"] for o in outs.values())
     clean = all(v["kv_clean_at_drain"] for v in results.values())
@@ -1297,6 +1289,7 @@ def bench_fault_sweep(cfg, params) -> bool:
 
 
 def main(n_requests: int = 24) -> None:
+    enable_compile_cache()
     cfg = get_config("qwen3-8b").reduced()
     params = model.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
@@ -1330,7 +1323,7 @@ def main(n_requests: int = 24) -> None:
     ok_chunked = bench_chunked_prefill(cfg, params)
     ok_horizon = bench_decode_horizon(cfg, params)
     ok_priority = bench_priority_mix(cfg, params)
-    ok_shard = bench_shard_sweep()
+    ok_shard = bench_shard_sweep(cfg, params)
     ok_kernel = bench_kernel_sweep(cfg, params)
     ok_spec = bench_spec_sweep(cfg, params)
     ok_fault = bench_fault_sweep(cfg, params)
@@ -1341,7 +1334,4 @@ def main(n_requests: int = 24) -> None:
 
 
 if __name__ == "__main__":
-    if "--shard-child" in sys.argv:
-        _shard_child(int(sys.argv[sys.argv.index("--shard-child") + 1]))
-        sys.exit(0)
     main(n_requests=12 if "--fast" in sys.argv else 24)

@@ -1,14 +1,15 @@
-"""Version-tolerant Pallas TPU API lookups and the platform gate.
+"""Pallas TPU compiler params, the interpret-mode rule and the platform gate.
 
-JAX renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``
-(and kept only one spelling per release).  Resolve whichever exists at
-import time so the kernels run against both API generations.
+:func:`default_interpret` is the one place that decides how a Pallas
+kernel runs: natively on a TPU backend, through the interpreter on every
+other backend.  No environment variable or option overrides it.
 
 :func:`pallas_supported` is the serve engine's capability gate (rung 2
 of the fallback ladder in docs/kernel_variants.md): the ``pallas``
 decode/prefill variants are only registered on the VPE axes when a
-trivial pallas_call actually executes on this process's backend —
-natively on TPU, via ``interpret=True`` everywhere else.
+trivial pallas_call actually executes on this process's backend.  A
+probe that raises is an error, never a demotion — a Mosaic failure on
+the chip must stop the program, not quietly serve the gather path.
 """
 
 from __future__ import annotations
@@ -20,8 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+CompilerParams = pltpu.CompilerParams
 
 _PALLAS_OK: Optional[bool] = None
 
@@ -32,25 +32,17 @@ def default_interpret() -> bool:
 
 
 def pallas_supported() -> bool:
-    """True when Pallas kernels can run here (probe result is cached).
-
-    Runs one trivial pallas_call at first ask; any failure — missing
-    mosaic support, an interpreter regression, an exotic backend —
-    resolves to False, and the engine's fallback ladder routes the
-    pallas variants to the gather path instead of crashing mid-serve.
-    """
+    """True when a trivial Pallas kernel computes the right answer here
+    (probe result is cached).  Exceptions from the probe propagate."""
     global _PALLAS_OK
     if _PALLAS_OK is None:
-        try:
-            def _probe(x_ref, o_ref):
-                o_ref[...] = x_ref[...] + 1.0
+        def _probe(x_ref, o_ref):
+            o_ref[...] = x_ref[...] + 1.0
 
-            out = pl.pallas_call(
-                _probe,
-                out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
-                interpret=default_interpret(),
-            )(jnp.zeros((8, 128), jnp.float32))
-            _PALLAS_OK = bool(out[0, 0] == 1.0)
-        except Exception:
-            _PALLAS_OK = False
+        out = pl.pallas_call(
+            _probe,
+            out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+            interpret=default_interpret(),
+        )(jnp.zeros((8, 128), jnp.float32))
+        _PALLAS_OK = bool(out[0, 0] == 1.0)
     return _PALLAS_OK

@@ -5,9 +5,11 @@ The paper accelerates a naive square-kernel 2-D convolution (its contour
 (8, 128) vector unit, so the natural decomposition is shift-and-MAC over
 the (kh, kw) taps — each tap is one full-width vector FMA, unrolled at
 trace time (kh*kw is small and static).  The output is blocked over
-rows; the input stays resident in VMEM (a 1024x1024 f32 frame is 4 MiB
-— half the VMEM budget; larger frames are row-chunked by the ops.py
-wrapper before reaching the kernel).
+rows; the whole input frame stays resident in VMEM, so the frame size
+bounds what compiles: the paper's 384x384 f32 frames fit, while a
+2048x2048 f32 frame (16 MiB) exceeds v5e's 16 MiB scoped-VMEM limit and
+the compiler refuses it.  Nothing chunks a larger frame before it
+reaches the kernel.
 
 Blocking the *output* only sidesteps the halo problem: overlapping input
 windows cannot be expressed as disjoint BlockSpec tiles, so the kernel

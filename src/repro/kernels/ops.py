@@ -5,24 +5,22 @@ block multiples, masking padded keys), pick block sizes, and fall back
 to the jnp oracle for shapes too small to tile — so callers (VPE
 variants, model layers) can use them unconditionally.
 
-``interpret`` defaults to True because this container is CPU-only; a
-real TPU deployment flips REPRO_PALLAS_INTERPRET=0.
+Kernels run natively on a TPU backend and through the Pallas interpreter
+everywhere else (:func:`compat.default_interpret`, read per call).
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from . import ref
+from .compat import default_interpret
 from .conv2d import conv2d_pallas
 from .flash_attention import flash_attention_pallas
 from .matmul import matmul_pallas
-
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -48,7 +46,7 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bk: int = 256, bn: int 
     mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
     ap = _pad_axis(_pad_axis(a, 0, mp), 1, kp)
     bp = _pad_axis(_pad_axis(b, 0, kp), 1, np_)
-    out = matmul_pallas(ap, bp, bm=bm, bk=bk, bn=bn, interpret=INTERPRET)
+    out = matmul_pallas(ap, bp, bm=bm, bk=bk, bn=bn, interpret=default_interpret())
     return out[:m, :n]
 
 
@@ -61,7 +59,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, bh: int = 8) -> jax.Array:
         return ref.conv2d_ref(x, w)
     hp_out = _round_up(h_out, bh)
     xp = _pad_axis(x, 0, hp_out + kh - 1)
-    out = conv2d_pallas(xp, w, bh=bh, interpret=INTERPRET)
+    out = conv2d_pallas(xp, w, bh=bh, interpret=default_interpret())
     return out[:h_out]
 
 
@@ -89,6 +87,6 @@ def flash_attention(
         qp, kp, vp,
         causal=causal, window=window, scale=scale,
         bq=bq, bk=bk, t_valid=T, q_offset=T - S,
-        interpret=INTERPRET,
+        interpret=default_interpret(),
     )
     return out[:, :, :S, :]

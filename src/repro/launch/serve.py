@@ -13,6 +13,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import VPE
+from repro.distributed import sharding as sharding_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.runtime.serve_faults import FaultPlan
 from repro.runtime.serve_loop import (
@@ -27,8 +29,8 @@ kernel backends (--decode-impl / --prefill-kernel):
   lets the VPE controller measure both backends per bucket x mesh and
   route to the winner.  Fallback ladder (docs/kernel_variants.md): a
   pinned or selected 'pallas' degrades to the gather path when the
-  layout has no pages, the platform fails the pallas probe, or the
-  mesh's Hkv % mp != 0 forces KV replication — it never crashes.
+  layout has no pages or the mesh's Hkv % mp != 0 forces KV
+  replication.  A kernel that fails to compile or run is an error.
 
 speculative decoding (--spec-draft):
   an n-gram table drafted from emitted tokens proposes up to S-1
@@ -179,6 +181,7 @@ def main() -> None:
                          "device CPU needs XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N")
     args = ap.parse_args()
+    enable_compile_cache()
     try:
         dp, mp = (int(x) for x in args.mesh.split(","))
     except ValueError:
@@ -193,7 +196,12 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed))
+    # a single tensor-sharded engine gets its parameters made in place
+    # on its mesh; replicas (dp > 1) copy theirs from the default device
+    mesh = (sharding_lib.serve_mesh(1, mp)
+            if args.continuous and dp == 1 and mp > 1 else None)
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed),
+                                   mesh=mesh)
     rng = np.random.default_rng(args.seed)
     def _prio(i: int) -> str:
         if args.priority == "mix":

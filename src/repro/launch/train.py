@@ -20,6 +20,7 @@ from repro.configs import get_config
 from repro.core import VPE
 from repro.data import DataConfig, SyntheticStream
 from repro.distributed import sharding as shardlib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_lib
 from repro.optim import adamw
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--no-vpe", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

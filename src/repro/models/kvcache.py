@@ -35,10 +35,12 @@ rolled back afterwards.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 Cache = Dict[str, jax.Array]
 
@@ -622,6 +624,30 @@ def decode_attention_pallas(
                             ring_window=ring_window)
 
 
+def _per_head_shard(kernel, q: jax.Array, pool_k_l: jax.Array,
+                    pool_v_l: jax.Array, *tables: jax.Array) -> jax.Array:
+    """Run a paged kernel on each tensor shard's own KV heads.
+
+    GSPMD cannot partition a Mosaic kernel.  When the caller has set a
+    mesh (``jax.set_mesh``) whose ``mp`` axis splits the heads, the call
+    runs under ``shard_map``: ``q`` (B, Hq, C, D) and the pages
+    (N, Hkv, bs, D) split on their head axis, block tables and lengths
+    whole on every shard.  Each shard then attends its ``Hq/mp`` query
+    heads against its ``Hkv/mp`` KV heads, which the serve engine only
+    permits when ``Hkv % mp == 0`` (``sharding.kernel_shard_ok``).
+    Without such a mesh the kernel sees every head.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or dict(mesh.shape).get("mp", 1) == 1:
+        return kernel(q, pool_k_l, pool_v_l, *tables)
+    heads = P(None, "mp", None, None)
+    # check_vma=False: pallas_call's output shape carries no varying-axes
+    # annotation; the out_specs above state it
+    return jax.shard_map(
+        kernel, in_specs=(heads, heads, heads) + (P(),) * len(tables),
+        out_specs=heads, check_vma=False)(q, pool_k_l, pool_v_l, *tables)
+
+
 def paged_decode_attention_kernel(
     q: jax.Array, pool_k_l: jax.Array, pool_v_l: jax.Array,
     block_table: jax.Array, length: jax.Array,
@@ -637,9 +663,10 @@ def paged_decode_attention_kernel(
     exactly the values the gather path reads (token-parity contract).
     """
     from repro.kernels.paged_attention import paged_attention_pallas
-    return paged_attention_pallas(
-        q, pool_k_l, pool_v_l, block_table, length,
-        window=window, scale=scale, read_dtype=read_dtype)
+    kernel = functools.partial(paged_attention_pallas, window=window,
+                               scale=scale, read_dtype=read_dtype)
+    return _per_head_shard(kernel, q, pool_k_l, pool_v_l, block_table,
+                           length)
 
 
 def paged_prefill_attention_kernel(
@@ -653,9 +680,13 @@ def paged_prefill_attention_kernel(
     ``chunk_len`` may be a traced scalar — it becomes a scalar-prefetch
     operand, not a recompile."""
     from repro.kernels.paged_attention import paged_prefill_attention_pallas
-    return paged_prefill_attention_pallas(
-        q, pool_k_l, pool_v_l, block_table, base,
-        chunk_len=chunk_len, window=window, scale=scale)
+
+    def kernel(q, pool_k_l, pool_v_l, block_table, base, chunk_len):
+        return paged_prefill_attention_pallas(
+            q, pool_k_l, pool_v_l, block_table, base,
+            chunk_len=chunk_len, window=window, scale=scale)
+    return _per_head_shard(kernel, q, pool_k_l, pool_v_l, block_table,
+                           base, jnp.asarray(chunk_len, jnp.int32))
 
 
 # Serve-engine VPE axis: decode-attention implementations (first = default).

@@ -2,7 +2,7 @@
 
 Every family exposes the same functional surface:
 
-    init_params(cfg, rng)                -> params pytree
+    init_params(cfg, rng[, mesh])        -> params pytree (placed)
     param_specs(cfg)                     -> ShapeDtypeStruct pytree (no alloc)
     forward(cfg, params, batch)          -> logits (B, S, V) f32
     loss_fn(cfg, params, batch)          -> scalar CE loss
@@ -16,12 +16,15 @@ stubbed modality frontend output, per the brief).
 
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+import functools
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from repro.configs.base import ModelConfig
+from repro.distributed import sharding
 
 from . import encdec, hybrid, kvcache, ssm_stack, transformer
 
@@ -41,14 +44,28 @@ def family_module(cfg: ModelConfig):
 
 # -- params ------------------------------------------------------------------------
 
-def init_params(cfg: ModelConfig, rng) -> Any:
-    return family_module(cfg).init_params(cfg, rng)
+@functools.partial(jax.jit, static_argnames=("cfg", "mesh"))
+def init_params(cfg: ModelConfig, rng, mesh: Optional[Mesh] = None) -> Any:
+    """Parameters in their final dtype and placement, made by one jit.
+
+    Inside the jit each float32 draw fuses with its cast, so no float32
+    copy of a weight reaches device memory.  With ``mesh`` every leaf is
+    made under its :func:`~repro.distributed.sharding.param_specs`
+    sharding, so a device creates only its own shard; without one the
+    parameters land on the default device.
+    """
+    params = family_module(cfg).init_params(cfg, rng)
+    if mesh is not None:
+        params = jax.lax.with_sharding_constraint(
+            params, sharding.param_shardings(params, mesh))
+    return params
 
 
 def param_specs(cfg: ModelConfig) -> Any:
     """Abstract params via eval_shape — zero allocation, dtype-faithful."""
     rng_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    return jax.eval_shape(lambda k: init_params(cfg, k), rng_spec)
+    return jax.eval_shape(
+        lambda k: family_module(cfg).init_params(cfg, k), rng_spec)
 
 
 def count_params_from_shapes(cfg: ModelConfig, active_only: bool = False) -> int:

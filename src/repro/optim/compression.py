@@ -81,12 +81,7 @@ def compressed_psum(x: jax.Array, mesh, axis_name: str, *, block: int = 256) -> 
     """All-reduce ``x`` over ``axis_name`` with int8 payload (shard_map)."""
     from jax.sharding import PartitionSpec as P
 
-    # version-tolerant: jax.shard_map is the promoted spelling, older
-    # releases only have jax.experimental.shard_map.shard_map
-    smap = getattr(jax, "shard_map", None)
-    if smap is None:
-        from jax.experimental.shard_map import shard_map as smap
     fn = partial(_compressed_psum_local, axis_name=axis_name, block=block)
-    return smap(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name)
     )(x)

@@ -796,12 +796,14 @@ class ContinuousBatchingEngine:
             self.mesh = sharding_lib.serve_mesh(dp, mp, devices=mesh_devices)
         # -- pallas capability gate (docs/kernel_variants.md ladder) --------
         # the kernel-backed variants are only offered when (1) the layout
-        # has pages at all, (2) a trivial pallas_call actually runs on
-        # this backend, and (3) the mesh's head sharding matches the
-        # kernel's shard contract (Hkv % mp == 0, so each shard sees its
-        # local head slice over the full unsharded page axis).  Anything
-        # short of that resolves to the gather path (_resolve_impl /
-        # _resolve_kernel) — a pinned "pallas" never crashes, it degrades.
+        # has pages at all, (2) a trivial pallas_call computes the right
+        # answer on this backend (a probe that raises propagates), and
+        # (3) the mesh's head sharding matches the kernel's shard
+        # contract (Hkv % mp == 0, so each shard sees its local head
+        # slice over the full unsharded page axis).  Anything short of
+        # that resolves to the gather path (_resolve_impl /
+        # _resolve_kernel): a pinned "pallas" degrades where it cannot
+        # apply.
         paged_capable = kv_layout in ("paged", "auto")
         self._pallas_ok = (paged_capable
                            and pallas_compat.pallas_supported()
@@ -835,7 +837,6 @@ class ContinuousBatchingEngine:
         self.proposer: Optional[NGramProposer] = (
             NGramProposer() if self.spec_draft != "off" else None)
         self._spec_fns: Dict[int, Callable] = {}
-        self._spec_fn_created = False
         # EMA of the per-call draft-acceptance fraction: the engine-level
         # workload signal quantized into the spec axis's bucket key
         # (None until the first measurement = neutral middle level).
@@ -852,7 +853,6 @@ class ContinuousBatchingEngine:
         self._probe_tick = 0
         self._spec_off_pending: Optional[Tuple[Tuple, str]] = None
         self._chunk_rr = 0           # round-robin cursor over prefilling slots
-        self._decode_fn_created = False
         # persistent device-side decode inputs: rebuilt from the host
         # slot mirrors only when an admission/retire/prefill-completion
         # event dirties them — a steady decode-bound step re-uploads
@@ -864,7 +864,6 @@ class ContinuousBatchingEngine:
         self._eos_dev = None
         self._masks_dirty = True
         self._fused_fns: Dict[Tuple[str, int], Callable] = {}
-        self._fused_fn_created = False
         self.stats = ServeStats()
         self.queue: List[Request] = []
         self.completed: List[Request] = []
@@ -952,7 +951,8 @@ class ContinuousBatchingEngine:
                         f"request needs to make progress")
                 n_pages = page_budget
             self.pages = PagePool(n_pages)
-            self.page_pool = model_lib.init_page_pool(cfg, n_pages, block_size)
+            self.page_pool = self._alloc(
+                lambda: model_lib.init_page_pool(cfg, n_pages, block_size))
             self._gather_pages = jax.jit(kvcache.gather_pages)
             self._write_pages = jax.jit(kvcache.write_pages, donate_argnums=0)
             # preemption swap: gather a victim's filled pages to host /
@@ -973,16 +973,17 @@ class ContinuousBatchingEngine:
             # selects "pallas" never traces it.
             self._prefill_chunks: Dict[str, Callable] = {}
         if kv_layout == "paged":
-            self.cache = model_lib.init_paged_cache(
-                cfg, slots, max_len, block_size, self.pages.trash_id)
+            self.cache = self._alloc(lambda: model_lib.init_paged_cache(
+                cfg, slots, max_len, block_size, self.pages.trash_id))
         elif kv_layout == "auto":
-            self.cache = {
+            self.cache = self._alloc(lambda: {
                 **model_lib.init_slot_cache(cfg, slots, max_len),
                 "bt": model_lib.init_paged_cache(
                     cfg, slots, max_len, block_size, self.pages.trash_id)["bt"],
-            }
+            })
         else:
-            self.cache = model_lib.init_slot_cache(cfg, slots, max_len)
+            self.cache = self._alloc(
+                lambda: model_lib.init_slot_cache(cfg, slots, max_len))
         self._prefill = jax.jit(
             lambda p, t, n: model_lib.prefill_slot_kv(cfg, p, t, n))
         # the old cache is dead after every insert — donate it so XLA
@@ -1033,9 +1034,9 @@ class ContinuousBatchingEngine:
                 self.prefix_cache = PrefixCache(prefix_blocks, block_size)
                 # pages live in the COMPUTE dtype so a warm suffix prefill
                 # sees bit-identical prefix K/V to a cold full prefill
-                self.block_pool = kvcache.init_block_pool(
+                self.block_pool = self._alloc(lambda: kvcache.init_block_pool(
                     prefix_blocks, cfg.num_layers, cfg.num_kv_heads,
-                    block_size, cfg.head_dim, dtype=jnp.dtype(cfg.dtype))
+                    block_size, cfg.head_dim, dtype=jnp.dtype(cfg.dtype)))
                 self._gather = jax.jit(kvcache.gather_blocks)
                 self._write_block = jax.jit(
                     lambda pool, k, v, bid, st: kvcache.write_block(
@@ -1055,39 +1056,43 @@ class ContinuousBatchingEngine:
                         "prefix_reuse", name, fn=(lambda name=name: name),
                         default=(i == 0))
         if self.mesh is not None:
-            self._shard_state()
+            self._place_params()
 
-    # -- mesh sharding -------------------------------------------------------
-    def _shard_state(self) -> None:
-        """Commit params + every KV container onto the engine's mesh.
+    # -- mesh placement ------------------------------------------------------
+    def _alloc(self, make: Callable[[], Any]) -> Any:
+        """Create a KV container where it lives.  On a mesh it is made
+        under one jit whose ``out_shardings`` are its
+        :func:`~repro.distributed.sharding.serve_cache_specs` — ``k``/
+        ``v`` split on ``Hkv`` over ``mp``, block tables and lengths
+        replicated — so no device ever holds more than its shard.
+        Without a mesh it lands on the default device."""
+        if self.mesh is None:
+            return make()
+        specs = sharding_lib.serve_cache_specs(jax.eval_shape(make),
+                                               self.mesh)
+        return jax.jit(make, out_shardings=self._named(specs))()
 
-        Params get the rule-table specs (heads / ffn hidden on the
-        tensor axis); KV containers shard the ``Hkv`` axis only
-        (:func:`~repro.distributed.sharding.serve_kv_spec`) so page ids,
-        block tables and lengths stay host-side replicated ints and
-        every layout's gather/scatter indexing is shard-local.  All
-        later engine jits see committed inputs and GSPMD propagates the
-        shardings through them — no per-call mesh plumbing."""
-        mesh = self.mesh
+    def _named(self, specs: Any) -> Any:
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, PartitionSpec))
 
-        def put(tree, specs):
-            return jax.device_put(tree, jax.tree.map(
-                lambda s: NamedSharding(mesh, s), specs,
-                is_leaf=lambda x: isinstance(x, PartitionSpec)))
+    def _place_params(self) -> None:
+        """Move each parameter leaf that is not already where the
+        rule table puts it on this engine's mesh (heads / ffn hidden /
+        vocab on ``mp``).  Parameters made by
+        ``model.init_params(cfg, rng, mesh)`` for the same mesh, or on
+        the one device of a ``(1, 1)`` replica row, stay where they are:
+        nothing is copied.  All later engine jits see committed inputs
+        and GSPMD propagates the shardings through them."""
+        target = self._named(sharding_lib.param_specs(self.params, self.mesh))
 
-        self.params = put(self.params,
-                          sharding_lib.param_specs(self.params, mesh))
-        self.cache = put(self.cache,
-                         sharding_lib.serve_cache_specs(self.cache, mesh))
-        if self.page_pool is not None:
-            self.page_pool = put(
-                self.page_pool,
-                sharding_lib.serve_cache_specs(self.page_pool, mesh))
-        block_pool = getattr(self, "block_pool", None)
-        if block_pool is not None:
-            self.block_pool = jax.device_put(
-                block_pool, NamedSharding(mesh, sharding_lib.serve_kv_spec(
-                    tuple(block_pool.shape), mesh)))
+        def put(x, s):
+            if isinstance(x, jax.Array) and x.sharding.is_equivalent_to(
+                    s, x.ndim):
+                return x
+            return jax.device_put(x, s)
+
+        self.params = jax.tree.map(put, self.params, target)
 
     # -- small jitted paged-state updates ----------------------------------
     @staticmethod
@@ -2210,10 +2215,7 @@ class ContinuousBatchingEngine:
             fns += [self._insert_at, self._prefill_suffix]
             if self.pages is None:
                 fns += [self._gather, self._write_block]
-        try:
-            return sum(f._cache_size() for f in fns)
-        except AttributeError:  # pragma: no cover - older/newer jax
-            return -1           # constant: comparison never skips a sample
+        return sum(f._cache_size() for f in fns)
 
     # -- contiguous-layout admission paths ----------------------------------
     def _prefill_full(self, i: int, prompt: np.ndarray):
@@ -2550,7 +2552,6 @@ class ContinuousBatchingEngine:
         self._last_variant = vname
         vname = self._resolve_impl(vname)
         fn = self._decode_fns.get(vname)
-        self._decode_fn_created = fn is None
         if fn is None:
             if self._decode_fns:
                 # an actual re-trace: a not-yet-compiled variant is baked
@@ -2599,7 +2600,6 @@ class ContinuousBatchingEngine:
         vname = self._resolve_impl(vname)
         key = (vname, horizon)
         fn = self._fused_fns.get(key)
-        self._fused_fn_created = fn is None
         if fn is None:
             if self._fused_fns or self._decode_fns:
                 self.stats.rejits += 1
@@ -2687,10 +2687,7 @@ class ContinuousBatchingEngine:
         must be dropped, exactly like a decode-fn compile."""
         if self.pages is None:
             return 0
-        try:
-            return self._set_bt._cache_size() + self._set_bt_many._cache_size()
-        except AttributeError:  # pragma: no cover - older/newer jax
-            return -1
+        return self._set_bt._cache_size() + self._set_bt_many._cache_size()
 
     def _rollback_reserved(self, i: int) -> None:
         """Return a slot's reserved-but-unwritten horizon pages to the
@@ -2737,10 +2734,7 @@ class ContinuousBatchingEngine:
                                   levels=self.occupancy_levels) \
             + self._shard_tail
         fn = self._fused_fn(bucket, H)
-        try:
-            jits = fn._cache_size()
-        except AttributeError:  # pragma: no cover - older/newer jax
-            jits = -1
+        jits = fn._cache_size()
         budget = np.zeros((self.num_slots,), np.int32)
         for i, rem in remaining.items():
             budget[i] = rem
@@ -2780,11 +2774,8 @@ class ContinuousBatchingEngine:
             rows = ([fault.slot] if fault.slot is not None
                     and fault.slot in remaining else list(remaining))
             toks[rows, :] = -1
-        if jits == -1:
-            step_tainted = self._fused_fn_created
-        else:
-            step_tainted = fn._cache_size() != jits
-        if bt_jits != -1 and self._bt_jit_cache_size() != bt_jits:
+        step_tainted = fn._cache_size() != jits
+        if self._bt_jit_cache_size() != bt_jits:
             step_tainted = True     # a splice jit compiled inside t_h
         if tripped:
             step_tainted = True     # a stalled wall must not feed axes
@@ -2878,7 +2869,6 @@ class ContinuousBatchingEngine:
         in the key — the verify read is the multi-query chunked-prefill
         generalization, not one of the single-token kernels."""
         fn = self._spec_fns.get(span)
-        self._spec_fn_created = fn is None
         if fn is None:
             if self._spec_fns or self._fused_fns or self._decode_fns:
                 self.stats.rejits += 1
@@ -2999,10 +2989,7 @@ class ContinuousBatchingEngine:
             if i in remaining:
                 tokens[i, 1:] = self.proposer.draft(i, S - 1)
         fn = self._spec_fn(S)
-        try:
-            jits = fn._cache_size()
-        except AttributeError:  # pragma: no cover - older/newer jax
-            jits = -1
+        jits = fn._cache_size()
         budget = np.zeros((self.num_slots,), np.int32)
         for i, rem in remaining.items():
             budget[i] = rem
@@ -3034,11 +3021,8 @@ class ContinuousBatchingEngine:
             rows = ([fault.slot] if fault.slot is not None
                     and fault.slot in remaining else list(remaining))
             toks[rows, :] = -1
-        if jits == -1:
-            step_tainted = self._spec_fn_created
-        else:
-            step_tainted = fn._cache_size() != jits
-        if bt_jits != -1 and self._bt_jit_cache_size() != bt_jits:
+        step_tainted = fn._cache_size() != jits
+        if self._bt_jit_cache_size() != bt_jits:
             step_tainted = True     # a splice jit compiled inside t_h
         if tripped:
             step_tainted = True     # a stalled wall must not feed axes
@@ -3109,7 +3093,17 @@ class ContinuousBatchingEngine:
         then ONE decode step advances the decoding slots — so the wall
         between two decode steps is bounded by the chunk budget, not by
         the longest queued prompt (``stats.decode_stall_s`` records that
-        bound being exercised)."""
+        bound being exercised).
+
+        On a tensor-sharded mesh (``mp > 1``) the iteration runs with
+        the mesh set, which is how the paged Pallas kernels find the
+        head axis to ``shard_map`` over (``kvcache._per_head_shard``)."""
+        if self.mesh_shape[1] == 1:
+            return self._step()
+        with jax.set_mesh(self.mesh):
+            return self._step()
+
+    def _step(self) -> bool:
         self._shed_expired()     # deadline sweep at the step boundary
         had_decoders = self.num_decoding > 0
         admits_before = len(self.stats.queue_wait_s)
@@ -3211,10 +3205,7 @@ class ContinuousBatchingEngine:
             self._span_device_fault(
                 rung, touched, "injected device fault in decode step")
             return True
-        try:
-            decode_jits = fn._cache_size()
-        except AttributeError:  # pragma: no cover - older/newer jax
-            decode_jits = -1
+        decode_jits = fn._cache_size()
         t0 = time.perf_counter()
         if self.kv_layout == "paged":
             self.page_pool, cache, next_tok = fn(
@@ -3249,11 +3240,8 @@ class ContinuousBatchingEngine:
         # feed the per-slot attribution (decode shapes are static here,
         # so compiles happen exactly when a variant is first baked in —
         # the jit-cache growth check also catches any recompile)
-        if decode_jits == -1:
-            step_tainted = self._decode_fn_created
-        else:
-            step_tainted = fn._cache_size() != decode_jits
-        if bt_jits != -1 and self._bt_jit_cache_size() != bt_jits:
+        step_tainted = fn._cache_size() != decode_jits
+        if self._bt_jit_cache_size() != bt_jits:
             step_tainted = True     # a splice jit compiled inside t_h
         if tripped:
             step_tainted = True     # a stalled wall must not feed axes
