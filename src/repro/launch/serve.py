@@ -65,6 +65,16 @@ fault tolerance (--fault-seed / --watchdog / --deadline):
 """
 
 
+def init_serve_params(cfg, seed: int, mp: int):
+    """Parameters made in place on the first replica row's ``mp``
+    devices (sharded there when ``mp > 1``, else on the default
+    device).  Replica 0 then keeps them where they are and every other
+    replica copies its own shards from there, so no device ever holds
+    more than one replica's shard of the model."""
+    mesh = sharding_lib.serve_mesh(1, mp) if mp > 1 else None
+    return model_lib.init_params(cfg, jax.random.PRNGKey(seed), mesh=mesh)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(
         epilog=_EPILOG,
@@ -196,12 +206,7 @@ def main() -> None:
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.reduced()
-    # a single tensor-sharded engine gets its parameters made in place
-    # on its mesh; replicas (dp > 1) copy theirs from the default device
-    mesh = (sharding_lib.serve_mesh(1, mp)
-            if args.continuous and dp == 1 and mp > 1 else None)
-    params = model_lib.init_params(cfg, jax.random.PRNGKey(args.seed),
-                                   mesh=mesh)
+    params = init_serve_params(cfg, args.seed, mp if args.continuous else 1)
     rng = np.random.default_rng(args.seed)
     def _prio(i: int) -> str:
         if args.priority == "mix":

@@ -57,6 +57,9 @@ def test_phase_serves_and_passes_the_reference_check(cs, phase):
     assert r["served_per_replica"] == [2 * traffic.n]
     assert r["checked_tokens"] > 0
     assert 0.0 <= r["max_logit_gap"] <= cs.LOGIT_TOL
+    assert r["argmax_agreement"] >= cs.MIN_AGREEMENT
+    assert r["top2_spacing_median"] > 0.0
+    assert 0.0 <= r["near_tie_share"] <= 1.0
     assert r["compiles"] > 0 and r["compile_s"] > 0.0
     # both passes have the same shapes: the second compiles nothing
     assert r["steady_compiles"] == 0
@@ -67,13 +70,14 @@ def test_report_prints_every_field(cs, phase, capsys):
     cs._report("cpu", phase[1])
     out = capsys.readouterr().out
     for needle in ("requests served", "compilations", "smoke reading",
-                   "teacher-forced check passed", "max logit gap"):
+                   "teacher-forced check passed", "max logit gap",
+                   "top-2 spacing median"):
         assert needle in out
 
 
 def test_reference_check_rejects_a_wrong_token(cs, params):
-    """The check has teeth: one generated token replaced by a token the
-    model does not favour fails it."""
+    """One generated token replaced by a token the model does not favour
+    fails the check; the same request served cleanly passes it."""
     traffic = cs.Traffic(1, (20, 20), (9,))
     eng = ContinuousBatchingEngine(CFG, params, kv_layout="paged", **{
         k: v for k, v in SMALL.items() if k != "prefill_chunk"})
@@ -86,6 +90,60 @@ def test_reference_check_rejects_a_wrong_token(cs, params):
     req.out[0] = int(np.argmin(np.asarray(logits[len(req.prompt) - 1])))
     with pytest.raises(cs.SmokeFailure, match="below the reference"):
         cs.check_against_reference(CFG, params, [req], SMALL["max_len"])
+
+
+def test_reference_check_floors_argmax_agreement(cs, params, monkeypatch):
+    """Tokens that are each the reference's runner-up pass any loose
+    tolerance but fail the agreement floor."""
+    monkeypatch.setattr(cs, "LOGIT_TOL", float("inf"))
+    (req,) = cs.Traffic(1, (20, 20), (9,)).requests(CFG.vocab_size, seed=3)
+    for _ in range(req.max_new_tokens):
+        seq = np.concatenate([req.prompt, np.asarray(req.out, np.int32)])
+        logits = model.forward(CFG, params, {"tokens": seq[None]})[0, -1]
+        req.out.append(int(np.argsort(np.asarray(logits))[-2]))
+    with pytest.raises(cs.SmokeFailure, match="reference's argmax"):
+        cs.check_against_reference(CFG, params, [req], SMALL["max_len"])
+
+
+def _zero_second_page(eng, i):
+    """A zeroed KV page: the slot's second prompt page loses its K/V."""
+    pid = eng.slots[i].pages[1]
+    eng.page_pool = {k: a.at[:, pid].set(0) for k, a in eng.page_pool.items()}
+
+
+def _block_table_off_by_one(eng, i):
+    """A block-table entry one page off: column 0 reads page 1."""
+    eng.cache = dict(eng.cache, bt=eng.cache["bt"].at[i, 0].set(
+        eng.slots[i].pages[1]))
+
+
+def _serve_planted(cs, params, plant):
+    """Serve one request; once its prefill is done, ``plant`` (if any)
+    corrupts its KV so that every later decode step reads the fault."""
+    eng = ContinuousBatchingEngine(CFG, params, kv_layout="paged",
+                                   decode_horizon=cs.DECODE_HORIZON, **SMALL)
+    (req,) = cs.Traffic(1, (60, 60), (13,)).requests(CFG.vocab_size, seed=0)
+    eng.submit(req)
+    planted = plant is None
+    while eng.step():
+        for i, s in enumerate(eng.slots):
+            if not planted and s.req is req and not s.prefilling:
+                plant(eng, i)
+                planted = True
+    assert planted and len(req.out) == 13
+    return req
+
+
+@pytest.mark.parametrize("plant", [_zero_second_page, _block_table_off_by_one],
+                         ids=["zeroed_page", "block_table_off_by_one"])
+def test_reference_check_catches_a_planted_kv_fault(cs, params, plant):
+    """A realistic engine fault fails the check, which the same request
+    served without it passes."""
+    clean = _serve_planted(cs, params, None)
+    cs.check_against_reference(CFG, params, [clean], SMALL["max_len"])
+    faulty = _serve_planted(cs, params, plant)
+    with pytest.raises(cs.SmokeFailure, match="below the reference"):
+        cs.check_against_reference(CFG, params, [faulty], SMALL["max_len"])
 
 
 def test_main_refuses_the_cpu(cs, capsys):

@@ -323,3 +323,54 @@ class TestMeshParity:
         keys = [b for (op, b) in vpe.controller._decisions
                 if op == "serve_decode_impl"]
         assert keys and all("shard" in b and (1, 2) == b[-2:] for b in keys)
+
+
+_REPLICA_PLACEMENT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from repro.configs import ARCHS
+from repro.launch.serve import init_serve_params
+from repro.runtime.serve_loop import make_serve_engine
+
+cfg = ARCHS["qwen3-8b"].reduced()
+devs = jax.devices()
+params = init_serve_params(cfg, 0, mp=2)
+
+def per_device(tree):
+    out = {}
+    for leaf in jax.tree.leaves(tree):
+        for sh in leaf.addressable_shards:
+            out[sh.device.id] = out.get(sh.device.id, 0) + sh.data.nbytes
+    return out
+
+total = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+made = per_device(params)
+assert set(made) == {devs[0].id, devs[1].id}, made
+assert max(made.values()) < total, (made, total)
+group = make_serve_engine(cfg, params, mesh_shape=(2, 2), slots=2,
+                          max_len=32, kv_layout="paged")
+r0, r1 = group.engines
+assert all(a is b for a, b in zip(jax.tree.leaves(r0.params),
+                                  jax.tree.leaves(params)))
+held = per_device(r1.params)
+assert set(held) == {devs[2].id, devs[3].id}, held
+assert held[devs[2].id] == made[devs[0].id], (held, made)
+print("PLACEMENT-OK")
+"""
+
+
+def test_replica_group_params_never_gather_on_one_device():
+    """``--mesh 2,2``: parameters are made sharded on the first replica
+    row; replica 0 keeps them and replica 1 copies only its own shards,
+    so no device holds the whole model.  Four forced host devices in a
+    subprocess keep this session's single CPU device."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    out = subprocess.run(
+        [sys.executable, "-c", _REPLICA_PLACEMENT], capture_output=True,
+        text=True, timeout=300, cwd=root,
+        env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"})
+    assert "PLACEMENT-OK" in out.stdout, out.stderr[-3000:]
