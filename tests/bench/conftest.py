@@ -1,0 +1,7 @@
+import pathlib
+import sys
+
+# the benchmark is imported as the package ``bench`` from the repo root
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
