@@ -1,8 +1,8 @@
 """Where JAX keeps its persistent compilation cache.
 
 One rule for every entry point (``chip_smoke.py``, ``launch/serve.py``,
-``launch/train.py``, ``benchmarks/serve_bench.py``), applied by calling
-:func:`enable_compile_cache` when the program starts, never at import:
+``launch/train.py``), applied by calling :func:`enable_compile_cache`
+when the program starts, never at import:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads that directory itself and
   nothing is set in code;

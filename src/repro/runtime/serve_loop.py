@@ -93,17 +93,29 @@ page pool does, and :class:`EngineReplicaGroup` quarantines a replica
 whose step faults terminally or whose watchdog-wrapped fence trips
 repeatedly, migrating its in-flight requests onto survivors and
 re-admitting it after a canary passes.  The engine itself never raises.
+
+The engine's phases are ``jax.profiler`` spans (``serve.step`` around an
+iteration, ``serve.admit``, ``serve.prefill_chunk``, ``serve.decode``,
+``serve.replay``, ...), each with its work counts as span stats, and
+every host block on a device value runs inside a ``<phase>.wait`` span.
+A profiler trace of a running engine then puts each device idle gap
+down to a phase, and a step's host self time is its span minus its
+waits (docs/tracing.md).  Until a trace is started the spans record
+nothing and cost a few microseconds each.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
@@ -863,6 +875,9 @@ class ContinuousBatchingEngine:
         self._use_paged_dev = None
         self._eos_dev = None
         self._masks_dirty = True
+        # the innermost open profiler span: names the ``.wait`` spans
+        # opened inside it (``serve`` outside every engine span)
+        self._phase = "serve"
         self._fused_fns: Dict[Tuple[str, int], Callable] = {}
         self.stats = ServeStats()
         self.queue: List[Request] = []
@@ -1408,8 +1423,9 @@ class ContinuousBatchingEngine:
         nb_pad = pad_to_bucket(nb, minimum=1)
         ids_pad = np.asarray(ids + [ids[0]] * (nb_pad - nb), np.int32)
         k, v = self._swap_gather(self.page_pool, jnp.asarray(ids_pad))
-        # np.asarray fences AND copies off-device: this IS the swap
-        slot.req.swap = (np.asarray(k), np.asarray(v), filled)
+        # the fetch fences AND copies off-device: this IS the swap
+        k, v = self._wait((k, v), jax.device_get)
+        slot.req.swap = (k, v, filled)
         self.stats.swap_outs += 1
         self.stats.swapped_pages += nb
 
@@ -1563,6 +1579,39 @@ class ContinuousBatchingEngine:
             elif slot.layout == "paged":
                 self._rollback_reserved(i)
 
+    # -- profiler spans (docs/tracing.md) -------------------------------------
+    @contextlib.contextmanager
+    def _span(self, name: str, **stats: int):
+        """One engine phase as a ``jax.profiler`` span with its work
+        counts as stats; the ``.wait`` spans opened inside it take its
+        name."""
+        outer, self._phase = self._phase, name
+        try:
+            with TraceAnnotation(name, **stats):
+                yield
+        finally:
+            self._phase = outer
+
+    def _wait(self, value, fence: Callable = jax.block_until_ready):
+        """Every host block on a device value in the step goes through
+        here: ``fence(value)`` inside a ``<phase>.wait`` span (pass
+        ``np.asarray`` to bring the value to the host)."""
+        with TraceAnnotation(self._phase + ".wait"):
+            return fence(value)
+
+    def _decode_stats(self, rows: Iterable[int], horizon: int
+                      ) -> Dict[str, int]:
+        """``serve.decode``'s stats: the decoding slots in the call, its
+        horizon, the keys its first token attends to and the pages the
+        rows' block tables hold, each summed over the rows."""
+        n = keys = pages = 0
+        for i in rows:
+            slot = self.slots[i]
+            n += 1
+            keys += slot.pos + 1
+            pages += len(slot.pages)
+        return {"rows": n, "horizon": horizon, "keys": keys, "pages": pages}
+
     def _guarded_fence(self, value, fault) -> Tuple[np.ndarray, bool]:
         """Fence a decode span's token output, through the watchdog when
         one is armed; returns ``(host_value, tripped)``.
@@ -1579,14 +1628,15 @@ class ContinuousBatchingEngine:
             if self.watchdog is not None:
                 self.watchdog.trips += 1
             self.stats.watchdog_trips += 1
-            return np.asarray(value), True
+            return self._wait(value, np.asarray), True
+        tripped = False
         if self.watchdog is not None:
             try:
-                return np.asarray(self.watchdog.guard(value)), False
-            except StragglerTimeout as e:
+                self._wait(value, self.watchdog.guard)
+            except StragglerTimeout:
                 self.stats.watchdog_trips += 1
-                return np.asarray(value), True
-        return np.asarray(value), False
+                tripped = True
+        return self._wait(value, np.asarray), tripped
 
     def _deadline_expired(self, req: Request, now: float) -> bool:
         return (req.deadline_s is not None
@@ -1756,13 +1806,15 @@ class ContinuousBatchingEngine:
             slot.layout = layout
             slot.admit_bucket = lbucket
             slot.tainted = False
+            reuse = use_matched if variant == "reuse" else 0
             if layout == "paged":
                 # placement only — the prompt's compute runs as chunks
                 # interleaved with decode steps (:meth:`_run_prefill_chunks`)
                 try:
-                    self._place_paged(i, req,
-                                      use_matched if variant == "reuse" else 0,
-                                      rbucket, variant, occ)
+                    with self._span("serve.place", rid=req.rid,
+                                    matched=reuse):
+                        self._place_paged(i, req, reuse, rbucket, variant,
+                                          occ)
                 except _PagePressure:
                     # the full escalation ladder (free list -> tree
                     # eviction -> preemption) ran dry: roll the admission
@@ -1787,39 +1839,49 @@ class ContinuousBatchingEngine:
                     return
                 continue
             # -- contiguous: atomic admission (the monolithic baseline) --
-            jits_before = self._prefill_jit_cache_size()
-            t0 = time.perf_counter()
-            if use_matched and variant == "reuse":
-                first, k_all, v_all, base = self._prefill_from_prefix(
-                    i, prompt, req.cache_handle)
-                self.stats.prefix_tokens_saved += use_matched
-            else:
-                first, k_all, v_all, base = self._prefill_full(i, prompt)
-            # fence EVERYTHING the admission dispatched — otherwise that
-            # device time both undercounts this admission's sample and
-            # leaks into the NEXT decode step's VPE sample
-            jax.block_until_ready(self.cache)
-            if self.pages is not None:
-                jax.block_until_ready(self.page_pool)
-            dt = time.perf_counter() - t0
-            self.stats.prefill_s += dt
-            tainted = self._prefill_jit_cache_size() != jits_before
-            if rbucket is not None and not tainted:
-                # feed the measured TTFT contribution back: the controller
-                # blind-trials "recompute" and keeps whichever is faster
-                # for this matched-length bucket.  Samples that paid a
-                # fresh jit compile are dropped: a plen bucket spans many
-                # pad shapes, and one recorded multi-second compile would
-                # permanently flip the bucket.
-                self.vpe.profiler.record("prefix_reuse", variant, rbucket, dt)
-                self.vpe.controller.on_sample("prefix_reuse", rbucket, variant)
-            slot.admit_wall = dt
-            slot.tainted = tainted
-            self._enter_decode(i, first)
-            # population is off the TTFT critical path: the first token is
-            # already out; new full blocks enter the tree now
-            self._cache_extend(req, k_all, v_all, base, slot)
-            self._retire_if_done(i)
+            with self._span("serve.place", rid=req.rid, matched=reuse):
+                self._place_contiguous(i, req, prompt, reuse, rbucket,
+                                       variant)
+
+    def _place_contiguous(self, i: int, req: Request, prompt: np.ndarray,
+                          reuse: int, rbucket, variant: str) -> None:
+        """Contiguous admission, atomic: prefill the whole prompt (or the
+        suffix past a reused prefix) into the slot's region, emit the
+        first token, and feed the ``prefix_reuse`` sample."""
+        slot = self.slots[i]
+        jits_before = self._prefill_jit_cache_size()
+        t0 = time.perf_counter()
+        if reuse:
+            first, k_all, v_all, base = self._prefill_from_prefix(
+                i, prompt, req.cache_handle)
+            self.stats.prefix_tokens_saved += reuse
+        else:
+            first, k_all, v_all, base = self._prefill_full(i, prompt)
+        # fence EVERYTHING the admission dispatched — otherwise that
+        # device time both undercounts this admission's sample and
+        # leaks into the NEXT decode step's VPE sample
+        self._wait(self.cache)
+        if self.pages is not None:
+            self._wait(self.page_pool)
+        dt = time.perf_counter() - t0
+        self.stats.prefill_s += dt
+        tainted = self._prefill_jit_cache_size() != jits_before
+        if rbucket is not None and not tainted:
+            # feed the measured TTFT contribution back: the controller
+            # blind-trials "recompute" and keeps whichever is faster
+            # for this matched-length bucket.  Samples that paid a
+            # fresh jit compile are dropped: a plen bucket spans many
+            # pad shapes, and one recorded multi-second compile would
+            # permanently flip the bucket.
+            self.vpe.profiler.record("prefix_reuse", variant, rbucket, dt)
+            self.vpe.controller.on_sample("prefix_reuse", rbucket, variant)
+        slot.admit_wall = dt
+        slot.tainted = tainted
+        self._enter_decode(i, first)
+        # population is off the TTFT critical path: the first token is
+        # already out; new full blocks enter the tree now
+        self._cache_extend(req, k_all, v_all, base, slot)
+        self._retire_if_done(i)
 
     def _unadmit(self, i: int, req: Request) -> None:
         """Undo a half-done admission whose placement rolled back: free
@@ -2027,8 +2089,7 @@ class ContinuousBatchingEngine:
         self._page_row(i, pages, 0)
         if req.swap is not None:
             P = self._swap_in_slot(i)
-        jax.block_until_ready(self.cache)
-        jax.block_until_ready(self.page_pool)     # the COW copy, if any
+        self._wait((self.cache, self.page_pool))     # the COW copy, if any
         dt = time.perf_counter() - t0
         self.stats.kv_place_s.append(dt)
         self.stats.prefill_s += dt
@@ -2108,44 +2169,50 @@ class ContinuousBatchingEngine:
         clen = (S - base) if not slot.chunk else min(slot.chunk, S - base)
         pad = min(pad_to_bucket(clen, minimum=self.min_prompt_pad),
                   self.max_len)
-        toks = np.zeros((1, pad), np.int32)
-        toks[0, :clen] = prompt[base:base + clen]
-        row = self._bt_row(slot.pages)
-        prefill_fn = self._prefill_chunk_fn(slot.kernel)
-        jits_before = self._prefill_jit_cache_size()
-        t0 = time.perf_counter()
-        self.page_pool, logits = prefill_fn(
-            self.params, self.page_pool, jnp.asarray(row), jnp.asarray(toks),
-            jnp.int32(base), jnp.int32(clen))
-        # fence: an async chunk would leak its device time into the next
-        # decode step's VPE sample (and undercount this admission's)
-        jax.block_until_ready((self.page_pool, logits))
-        dt = time.perf_counter() - t0
-        slot.chunk_walls.append(dt)
-        # the scheduler objective's second term: charge the chunk for the
-        # class-weighted queue wait it imposed while it ran
-        slot.chunk_costs.append(
-            dt * (1.0 + self.slo_weight * self._queue_pressure()))
-        if self._prefill_jit_cache_size() != jits_before:
-            slot.tainted = True
-        self.stats.prefill_s += dt
-        self.stats.prefill_chunks += 1
-        if fault is not None:
-            if fault.kind == "nan":
-                # poisoned chunk logits: the K/V this chunk scattered is
-                # untrusted too — quarantine the slot (recompute-resume)
-                # before fill_pos could count the poisoned positions
-                self._numeric_fault(
-                    i, "injected NaN logits in prefill chunk")
-                return
-            # stall: the value arrived late — commit it, count the trip
-            if self.watchdog is not None:
-                self.watchdog.trips += 1
-            self.stats.watchdog_trips += 1
-            slot.tainted = True
-        slot.fill_pos = base + clen
-        if slot.fill_pos >= S:
-            self._finish_prefill(i, logits)
+        with self._span("serve.prefill_chunk", rid=req.rid, base=base,
+                        tokens=clen, padded=pad):
+            toks = np.zeros((1, pad), np.int32)
+            toks[0, :clen] = prompt[base:base + clen]
+            row = self._bt_row(slot.pages)
+            prefill_fn = self._prefill_chunk_fn(slot.kernel)
+            jits_before = self._prefill_jit_cache_size()
+            t0 = time.perf_counter()
+            self.page_pool, logits = prefill_fn(
+                self.params, self.page_pool, jnp.asarray(row),
+                jnp.asarray(toks), jnp.int32(base), jnp.int32(clen))
+            # fence: an async chunk would leak its device time into the
+            # next decode step's VPE sample (and undercount this
+            # admission's)
+            self._wait((self.page_pool, logits))
+            dt = time.perf_counter() - t0
+            slot.chunk_walls.append(dt)
+            # the scheduler objective's second term: charge the chunk for
+            # the class-weighted queue wait it imposed while it ran
+            slot.chunk_costs.append(
+                dt * (1.0 + self.slo_weight * self._queue_pressure()))
+            if self._prefill_jit_cache_size() != jits_before:
+                slot.tainted = True
+            self.stats.prefill_s += dt
+            self.stats.prefill_chunks += 1
+            if fault is not None:
+                if fault.kind == "nan":
+                    # poisoned chunk logits: the K/V this chunk scattered
+                    # is untrusted too — quarantine the slot (recompute-
+                    # resume) before fill_pos could count the poisoned
+                    # positions
+                    self._numeric_fault(
+                        i, "injected NaN logits in prefill chunk")
+                    return
+                # stall: the value arrived late — commit it, count the
+                # trip
+                if self.watchdog is not None:
+                    self.watchdog.trips += 1
+                self.stats.watchdog_trips += 1
+                slot.tainted = True
+            slot.fill_pos = base + clen
+            if slot.fill_pos >= S:
+                with self._span("serve.first_token", rid=req.rid):
+                    self._finish_prefill(i, logits)
 
     def _finish_prefill(self, i: int, logits) -> None:
         """Last chunk done: first token out, device length installed,
@@ -2158,7 +2225,7 @@ class ContinuousBatchingEngine:
         req = slot.req
         # resumed residencies prefilled prompt + prior output
         S = len(req.prompt) + len(req.out)
-        first = int(np.asarray(jnp.argmax(logits[0])))
+        first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
         self.cache = self._set_len(self.cache, jnp.int32(i), jnp.int32(S))
         slot.admit_wall = slot.place_wall + sum(slot.chunk_walls)
         if self.vpe is not None and not slot.tainted:
@@ -2228,12 +2295,12 @@ class ContinuousBatchingEngine:
         # fence the prefill BEFORE the placement timer: the insert fence
         # below transitively waits on its inputs, and an async prefill
         # inside the span would record the O(S^2) compute as "placement"
-        jax.block_until_ready(k)
+        self._wait(k)
         t0 = time.perf_counter()
         self.cache = self._insert(self.cache, k, v, jnp.int32(i), jnp.int32(S))
-        jax.block_until_ready(self.cache)
+        self._wait(self.cache)
         self.stats.kv_place_s.append(time.perf_counter() - t0)
-        first = int(np.asarray(jnp.argmax(logits[0])))
+        first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
         return first, k, v, 0
 
     def _prefill_from_prefix(self, i: int, prompt: np.ndarray, handle):
@@ -2261,7 +2328,7 @@ class ContinuousBatchingEngine:
         pk, pv = self._gather_prefix(ids)
         cache = self._insert_at(self.cache, pk, pv, jnp.int32(i), jnp.int32(0),
                                 jnp.int32(S))
-        jax.block_until_ready(cache)
+        self._wait(cache)
         self.stats.kv_place_s.append(time.perf_counter() - t0)
         sl = S - P
         pad_s = min(pad_to_bucket(sl, minimum=self.min_prompt_pad),
@@ -2272,7 +2339,7 @@ class ContinuousBatchingEngine:
             self.params, jnp.asarray(toks), pk, pv, jnp.int32(P), jnp.int32(sl))
         self.cache = self._insert_at(cache, k, v, jnp.int32(i), jnp.int32(P),
                                      jnp.int32(S))
-        first = int(np.asarray(jnp.argmax(logits[0])))
+        first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
         return first, k, v, P
 
     def _gather_prefix(self, ids: np.ndarray):
@@ -2395,7 +2462,7 @@ class ContinuousBatchingEngine:
             starts = [st for _, st in fresh]
             self._write_suffix_pages(k_all, v_all, ids, starts, base,
                                      int(len(req.prompt)))
-            jax.block_until_ready(self.page_pool)
+            self._wait(self.page_pool)
             return
         # one dispatch per fresh block: acceptable because it is paid only
         # when a prefix is seen for the FIRST time (the paper's warm-up
@@ -2407,7 +2474,7 @@ class ContinuousBatchingEngine:
                 jnp.int32(start - base))
         # fence the page writes: otherwise their device time leaks
         # into the next decode step's timed VPE sample
-        jax.block_until_ready(self.block_pool)
+        self._wait(self.block_pool)
 
     def _retire_if_done(self, i: int) -> None:
         slot = self.slots[i]
@@ -2665,18 +2732,19 @@ class ContinuousBatchingEngine:
         on-device output and the masks are unchanged."""
         if not self._masks_dirty:
             return
-        self._tok_dev = jnp.asarray(
-            np.array([s.tok for s in self.slots], np.int32))
-        self._live_dev = jnp.asarray(
-            np.array([0 if (s.free or s.prefilling) else 1
-                      for s in self.slots], np.int32))
-        self._eos_dev = jnp.asarray(
-            np.array([-1 if (s.req is None or s.req.eos_id is None)
-                      else s.req.eos_id for s in self.slots], np.int32))
-        if self.kv_layout == "auto":
-            self._use_paged_dev = jnp.asarray(
-                np.array([1 if s.layout == "paged" else 0
+        with self._span("serve.masks"):
+            self._tok_dev = jnp.asarray(
+                np.array([s.tok for s in self.slots], np.int32))
+            self._live_dev = jnp.asarray(
+                np.array([0 if (s.free or s.prefilling) else 1
                           for s in self.slots], np.int32))
+            self._eos_dev = jnp.asarray(
+                np.array([-1 if (s.req is None or s.req.eos_id is None)
+                          else s.req.eos_id for s in self.slots], np.int32))
+            if self.kv_layout == "auto":
+                self._use_paged_dev = jnp.asarray(
+                    np.array([1 if s.layout == "paged" else 0
+                              for s in self.slots], np.int32))
         self._masks_dirty = False
 
     def _bt_jit_cache_size(self) -> int:
@@ -2711,7 +2779,8 @@ class ContinuousBatchingEngine:
         fault = self._take_fault("fused")
         bt_jits = self._bt_jit_cache_size()
         if self.pages is not None:
-            self._grow_block_tables(span=H, remaining=remaining)
+            with self._span("serve.reserve", span=H):
+                self._grow_block_tables(span=H, remaining=remaining)
             # growth may have preempted decoding slots (including a
             # grower preempting itself): prune them from this call and
             # refresh the masks the preemption dirtied
@@ -2740,20 +2809,22 @@ class ContinuousBatchingEngine:
             budget[i] = rem
         bud_dev = jnp.asarray(budget)
         t0 = time.perf_counter()
-        if self.kv_layout == "paged":
-            self.page_pool, cache, tok_block, valid, final_tok = fn(
-                self.params, self.page_pool, self.cache, self._tok_dev,
-                self._live_dev, self._eos_dev, bud_dev)
-        elif self.kv_layout == "auto":
-            cache, self.page_pool, tok_block, valid, final_tok = fn(
-                self.params, self.cache, self.page_pool, self._tok_dev,
-                self._use_paged_dev, self._live_dev, self._eos_dev, bud_dev)
-        else:
-            cache, tok_block, valid, final_tok = fn(
-                self.params, self.cache, self._tok_dev, self._live_dev,
-                self._eos_dev, bud_dev)
-        toks, tripped = self._guarded_fence(tok_block, fault)
-        emits = np.asarray(valid)
+        with self._span("serve.decode", **self._decode_stats(remaining, H)):
+            if self.kv_layout == "paged":
+                self.page_pool, cache, tok_block, valid, final_tok = fn(
+                    self.params, self.page_pool, self.cache, self._tok_dev,
+                    self._live_dev, self._eos_dev, bud_dev)
+            elif self.kv_layout == "auto":
+                cache, self.page_pool, tok_block, valid, final_tok = fn(
+                    self.params, self.cache, self.page_pool, self._tok_dev,
+                    self._use_paged_dev, self._live_dev, self._eos_dev,
+                    bud_dev)
+            else:
+                cache, tok_block, valid, final_tok = fn(
+                    self.params, self.cache, self._tok_dev, self._live_dev,
+                    self._eos_dev, bud_dev)
+            toks, tripped = self._guarded_fence(tok_block, fault)
+            emits = self._wait(valid, np.asarray)
         dt = time.perf_counter() - t0
         self.cache = cache
         self._tok_dev = final_tok
@@ -2790,78 +2861,79 @@ class ContinuousBatchingEngine:
                                      dt / H)
             self.vpe.controller.on_sample(self._axis, bucket,
                                           self._last_variant)
-        share = dt / max(valid_total, 1)
-        vocab = self.cfg.vocab_size
-        quarantine: List[int] = []
-        probe_off = probe_acc = 0
-        self._probe_tick += 1
-        probing = (self.spec_draft == "auto"
-                   and self._probe_tick % self._probe_every == 0)
-        for i in remaining:
-            slot = self.slots[i]
-            # a slot freezes at most once, so its valid tokens are a
-            # contiguous prefix of the horizon
-            e = int(emits[i].sum())
-            new_toks = [int(t) for t in toks[i, :e]]
-            if any(t < 0 or t >= vocab for t in new_toks):
-                # always-on numeric validation: an out-of-range token
-                # means this call's logits for the slot were garbage —
-                # NOTHING from the call is committed for it (mid-span
-                # poison taints the whole span) and the slot is
-                # quarantined after the replay loop (preempting inside
-                # it would mutate the slots being iterated)
-                quarantine.append(i)
-                continue
-            slot.req.out.extend(new_toks)
-            if self.proposer is not None:
-                if probing and new_toks:
-                    # counterfactual probe BEFORE observe() advances the
-                    # slot's context (see _probe_accept)
-                    probe_acc += self._probe_accept(i, new_toks)
-                    probe_off += len(new_toks)
-                self.proposer.observe(i, new_toks)
-            slot.tok = new_toks[-1]
-            slot.pos += e
-            slot.steps_resident += e
-            if not step_tainted:
-                slot.clean_step_shares.extend([share] * e)
-            self.stats.tokens_out += e
-            if slot.layout == "paged":
-                self._rollback_reserved(i)
-            self._retire_if_done(i)
-        for i in quarantine:
-            self._numeric_fault(
-                i, "out-of-range token from fused horizon call")
-        self._update_accept_ema(probe_off, probe_acc)
-        if fault is None and not tripped:
-            self._tick_probation()
-        if self.vpe is not None and hbucket is not None \
-                and not step_tainted and valid_total:
-            # per-TOKEN wall of the FULL span (reservation + call +
-            # fence + replay — the overhead a horizon amortizes), with
-            # compile-tainted calls dropped; frozen steps emit nothing,
-            # so over-long horizons pay for themselves here.  The SLO
-            # charge factor makes the same wall cost MORE when queued
-            # (especially interactive) requests waited it out — the
-            # two-term objective's second term.
-            charge = 1.0 + self.slo_weight * self._queue_pressure()
-            self.vpe.profiler.record("decode_horizon", hname, hbucket,
-                                     (time.perf_counter() - t_h)
-                                     / valid_total * charge)
-            self.vpe.controller.on_sample("decode_horizon", hbucket, hname)
-        if self.vpe is not None and self._spec_off_pending is not None \
-                and not step_tainted and valid_total:
-            # the spec axis selected "off" (or its span clamped out)
-            # this step: the plain fused path IS the off variant, so
-            # its per-committed-token wall feeds the spec axis in the
-            # same units the verify path records — the off-vs-span
-            # comparison the controller runs per bucket
-            sb, sn = self._spec_off_pending
-            charge = 1.0 + self.slo_weight * self._queue_pressure()
-            self.vpe.profiler.record("spec_draft", sn, sb,
-                                     (time.perf_counter() - t_h)
-                                     / valid_total * charge)
-            self.vpe.controller.on_sample("spec_draft", sb, sn)
+        with self._span("serve.replay", tokens=valid_total):
+            share = dt / max(valid_total, 1)
+            vocab = self.cfg.vocab_size
+            quarantine: List[int] = []
+            probe_off = probe_acc = 0
+            self._probe_tick += 1
+            probing = (self.spec_draft == "auto"
+                       and self._probe_tick % self._probe_every == 0)
+            for i in remaining:
+                slot = self.slots[i]
+                # a slot freezes at most once, so its valid tokens are a
+                # contiguous prefix of the horizon
+                e = int(emits[i].sum())
+                new_toks = [int(t) for t in toks[i, :e]]
+                if any(t < 0 or t >= vocab for t in new_toks):
+                    # always-on numeric validation: an out-of-range token
+                    # means this call's logits for the slot were garbage —
+                    # NOTHING from the call is committed for it (mid-span
+                    # poison taints the whole span) and the slot is
+                    # quarantined after the replay loop (preempting inside
+                    # it would mutate the slots being iterated)
+                    quarantine.append(i)
+                    continue
+                slot.req.out.extend(new_toks)
+                if self.proposer is not None:
+                    if probing and new_toks:
+                        # counterfactual probe BEFORE observe() advances the
+                        # slot's context (see _probe_accept)
+                        probe_acc += self._probe_accept(i, new_toks)
+                        probe_off += len(new_toks)
+                    self.proposer.observe(i, new_toks)
+                slot.tok = new_toks[-1]
+                slot.pos += e
+                slot.steps_resident += e
+                if not step_tainted:
+                    slot.clean_step_shares.extend([share] * e)
+                self.stats.tokens_out += e
+                if slot.layout == "paged":
+                    self._rollback_reserved(i)
+                self._retire_if_done(i)
+            for i in quarantine:
+                self._numeric_fault(
+                    i, "out-of-range token from fused horizon call")
+            self._update_accept_ema(probe_off, probe_acc)
+            if fault is None and not tripped:
+                self._tick_probation()
+            if self.vpe is not None and hbucket is not None \
+                    and not step_tainted and valid_total:
+                # per-TOKEN wall of the FULL span (reservation + call +
+                # fence + replay — the overhead a horizon amortizes), with
+                # compile-tainted calls dropped; frozen steps emit nothing,
+                # so over-long horizons pay for themselves here.  The SLO
+                # charge factor makes the same wall cost MORE when queued
+                # (especially interactive) requests waited it out — the
+                # two-term objective's second term.
+                charge = 1.0 + self.slo_weight * self._queue_pressure()
+                self.vpe.profiler.record("decode_horizon", hname, hbucket,
+                                         (time.perf_counter() - t_h)
+                                         / valid_total * charge)
+                self.vpe.controller.on_sample("decode_horizon", hbucket, hname)
+            if self.vpe is not None and self._spec_off_pending is not None \
+                    and not step_tainted and valid_total:
+                # the spec axis selected "off" (or its span clamped out)
+                # this step: the plain fused path IS the off variant, so
+                # its per-committed-token wall feeds the spec axis in the
+                # same units the verify path records — the off-vs-span
+                # comparison the controller runs per bucket
+                sb, sn = self._spec_off_pending
+                charge = 1.0 + self.slo_weight * self._queue_pressure()
+                self.vpe.profiler.record("spec_draft", sn, sb,
+                                         (time.perf_counter() - t_h)
+                                         / valid_total * charge)
+                self.vpe.controller.on_sample("spec_draft", sb, sn)
 
     def _spec_fn(self, span: int) -> Callable:
         """The speculative-verify analogue of :meth:`_fused_fn`: one
@@ -2962,7 +3034,8 @@ class ContinuousBatchingEngine:
         fault = self._take_fault("spec")
         bt_jits = self._bt_jit_cache_size()
         if self.pages is not None:
-            self._grow_block_tables(span=S, remaining=remaining)
+            with self._span("serve.reserve", span=S):
+                self._grow_block_tables(span=S, remaining=remaining)
             remaining = {i: r for i, r in remaining.items()
                          if self.slots[i].req is not None
                          and not self.slots[i].prefilling}
@@ -2996,16 +3069,18 @@ class ContinuousBatchingEngine:
         bud_dev = jnp.asarray(budget)
         tok_dev = jnp.asarray(tokens)
         t0 = time.perf_counter()
-        if self.kv_layout == "paged":
-            self.page_pool, cache, tok_block, valid, final_tok = fn(
-                self.params, self.page_pool, self.cache, tok_dev,
-                self._live_dev, self._eos_dev, bud_dev)
-        else:
-            cache, self.page_pool, tok_block, valid, final_tok = fn(
-                self.params, self.cache, self.page_pool, tok_dev,
-                self._use_paged_dev, self._live_dev, self._eos_dev, bud_dev)
-        toks, tripped = self._guarded_fence(tok_block, fault)
-        emits = np.asarray(valid)
+        with self._span("serve.verify", rows=n_active, span=S):
+            if self.kv_layout == "paged":
+                self.page_pool, cache, tok_block, valid, final_tok = fn(
+                    self.params, self.page_pool, self.cache, tok_dev,
+                    self._live_dev, self._eos_dev, bud_dev)
+            else:
+                cache, self.page_pool, tok_block, valid, final_tok = fn(
+                    self.params, self.cache, self.page_pool, tok_dev,
+                    self._use_paged_dev, self._live_dev, self._eos_dev,
+                    bud_dev)
+            toks, tripped = self._guarded_fence(tok_block, fault)
+            emits = self._wait(valid, np.asarray)
         dt = time.perf_counter() - t0
         self.cache = cache
         self._tok_dev = final_tok
@@ -3029,61 +3104,62 @@ class ContinuousBatchingEngine:
         if step_tainted:
             self.stats.tainted_steps += 1
         valid_total = int(emits.sum())
-        share = dt / max(valid_total, 1)
-        vocab = self.cfg.vocab_size
-        quarantine: List[int] = []
-        offered_total = accepted_total = 0
-        for i in remaining:
-            slot = self.slots[i]
-            # committed tokens are a contiguous prefix of the span
-            # (match, budget and EOS masks are all prefixes)
-            e = int(emits[i].sum())
-            new_toks = [int(t) for t in toks[i, :e]]
-            if any(t < 0 or t >= vocab for t in new_toks):
-                # poisoned verify logits: commit nothing from this call
-                # for the slot, quarantine it after the replay loop
-                quarantine.append(i)
-                continue
-            # drafts this slot's budget could still have committed
-            # (committing k drafts needs k+1 <= budget), vs the drafts
-            # that actually landed (everything before the correction)
-            offered = min(S - 1, max(remaining[i] - 1, 0))
-            acc = max(e - 1, 0)
-            offered_total += offered
-            accepted_total += acc
-            self.stats.draft_tokens += offered
-            self.stats.accepted_tokens += acc
-            self.stats.accept_hist[acc] = \
-                self.stats.accept_hist.get(acc, 0) + 1
-            slot.req.out.extend(new_toks)
-            self.proposer.observe(i, new_toks)
-            slot.tok = new_toks[-1]
-            slot.pos += e
-            slot.steps_resident += e
-            if not step_tainted:
-                slot.clean_step_shares.extend([share] * e)
-            self.stats.tokens_out += e
-            if slot.layout == "paged":
-                self._rollback_reserved(i)
-            self._retire_if_done(i)
-        for i in quarantine:
-            self._numeric_fault(
-                i, "out-of-range token from speculative verify call")
-        self._update_accept_ema(offered_total, accepted_total)
-        if fault is None and not tripped:
-            self._tick_probation()
-        if self.vpe is not None and sbucket is not None \
-                and not step_tainted and valid_total:
-            # per-COMMITTED-token wall of the full span (drafting +
-            # reservation + call + fence + replay): a span whose drafts
-            # miss commits ~1 token per call and prices itself out —
-            # the same self-pricing contract as the horizon axis, with
-            # the accept rate doing the work the freeze mask does there
-            charge = 1.0 + self.slo_weight * self._queue_pressure()
-            self.vpe.profiler.record("spec_draft", sname, sbucket,
-                                     (time.perf_counter() - t_h)
-                                     / valid_total * charge)
-            self.vpe.controller.on_sample("spec_draft", sbucket, sname)
+        with self._span("serve.replay", tokens=valid_total):
+            share = dt / max(valid_total, 1)
+            vocab = self.cfg.vocab_size
+            quarantine: List[int] = []
+            offered_total = accepted_total = 0
+            for i in remaining:
+                slot = self.slots[i]
+                # committed tokens are a contiguous prefix of the span
+                # (match, budget and EOS masks are all prefixes)
+                e = int(emits[i].sum())
+                new_toks = [int(t) for t in toks[i, :e]]
+                if any(t < 0 or t >= vocab for t in new_toks):
+                    # poisoned verify logits: commit nothing from this call
+                    # for the slot, quarantine it after the replay loop
+                    quarantine.append(i)
+                    continue
+                # drafts this slot's budget could still have committed
+                # (committing k drafts needs k+1 <= budget), vs the drafts
+                # that actually landed (everything before the correction)
+                offered = min(S - 1, max(remaining[i] - 1, 0))
+                acc = max(e - 1, 0)
+                offered_total += offered
+                accepted_total += acc
+                self.stats.draft_tokens += offered
+                self.stats.accepted_tokens += acc
+                self.stats.accept_hist[acc] = \
+                    self.stats.accept_hist.get(acc, 0) + 1
+                slot.req.out.extend(new_toks)
+                self.proposer.observe(i, new_toks)
+                slot.tok = new_toks[-1]
+                slot.pos += e
+                slot.steps_resident += e
+                if not step_tainted:
+                    slot.clean_step_shares.extend([share] * e)
+                self.stats.tokens_out += e
+                if slot.layout == "paged":
+                    self._rollback_reserved(i)
+                self._retire_if_done(i)
+            for i in quarantine:
+                self._numeric_fault(
+                    i, "out-of-range token from speculative verify call")
+            self._update_accept_ema(offered_total, accepted_total)
+            if fault is None and not tripped:
+                self._tick_probation()
+            if self.vpe is not None and sbucket is not None \
+                    and not step_tainted and valid_total:
+                # per-COMMITTED-token wall of the full span (drafting +
+                # reservation + call + fence + replay): a span whose drafts
+                # miss commits ~1 token per call and prices itself out —
+                # the same self-pricing contract as the horizon axis, with
+                # the accept rate doing the work the freeze mask does there
+                charge = 1.0 + self.slo_weight * self._queue_pressure()
+                self.vpe.profiler.record("spec_draft", sname, sbucket,
+                                         (time.perf_counter() - t_h)
+                                         / valid_total * charge)
+                self.vpe.controller.on_sample("spec_draft", sbucket, sname)
 
     def step(self) -> bool:
         """One engine iteration; returns False when fully idle.
@@ -3097,18 +3173,26 @@ class ContinuousBatchingEngine:
 
         On a tensor-sharded mesh (``mp > 1``) the iteration runs with
         the mesh set, which is how the paged Pallas kernels find the
-        head axis to ``shard_map`` over (``kvcache._per_head_shard``)."""
-        if self.mesh_shape[1] == 1:
-            return self._step()
-        with jax.set_mesh(self.mesh):
-            return self._step()
+        head axis to ``shard_map`` over (``kvcache._per_head_shard``).
+
+        The iteration is one ``serve.step`` profiler span; its stats are
+        the state the step starts from (docs/tracing.md)."""
+        prefilling = sum(1 for s in self.slots if s.prefilling)
+        with self._span("serve.step", decode_steps=self.stats.decode_steps,
+                        queued=len(self.queue), decoding=self.num_decoding,
+                        prefilling=prefilling):
+            if self.mesh_shape[1] == 1:
+                return self._step()
+            with jax.set_mesh(self.mesh):
+                return self._step()
 
     def _step(self) -> bool:
         self._shed_expired()     # deadline sweep at the step boundary
         had_decoders = self.num_decoding > 0
         admits_before = len(self.stats.queue_wait_s)
         t_p = time.perf_counter()
-        self._admit()
+        with self._span("serve.admit", queued=len(self.queue)):
+            self._admit()
         ran_chunk = self._run_prefill_chunks()
         prefill_work = (ran_chunk
                         or len(self.stats.queue_wait_s) != admits_before)
@@ -3181,7 +3265,8 @@ class ContinuousBatchingEngine:
         fault = self._take_fault("decode")
         bt_jits = self._bt_jit_cache_size()
         if self.pages is not None:
-            self._grow_block_tables()
+            with self._span("serve.reserve", span=1):
+                self._grow_block_tables()
             n_active = self.num_decoding
             if n_active == 0:
                 return True     # growth preempted every decoder
@@ -3206,18 +3291,21 @@ class ContinuousBatchingEngine:
                 rung, touched, "injected device fault in decode step")
             return True
         decode_jits = fn._cache_size()
+        rows = [i for i, s in enumerate(self.slots)
+                if s.req is not None and not s.prefilling]
         t0 = time.perf_counter()
-        if self.kv_layout == "paged":
-            self.page_pool, cache, next_tok = fn(
-                self.params, self.page_pool, self.cache, self._tok_dev,
-                self._live_dev)
-        elif self.kv_layout == "auto":
-            cache, self.page_pool, next_tok = fn(
-                self.params, self.cache, self.page_pool, self._tok_dev,
-                self._use_paged_dev, self._live_dev)
-        else:
-            cache, next_tok = fn(self.params, self.cache, self._tok_dev)
-        toks, tripped = self._guarded_fence(next_tok, fault)
+        with self._span("serve.decode", **self._decode_stats(rows, 1)):
+            if self.kv_layout == "paged":
+                self.page_pool, cache, next_tok = fn(
+                    self.params, self.page_pool, self.cache, self._tok_dev,
+                    self._live_dev)
+            elif self.kv_layout == "auto":
+                cache, self.page_pool, next_tok = fn(
+                    self.params, self.cache, self.page_pool, self._tok_dev,
+                    self._use_paged_dev, self._live_dev)
+            else:
+                cache, next_tok = fn(self.params, self.cache, self._tok_dev)
+            toks, tripped = self._guarded_fence(next_tok, fault)
         dt = time.perf_counter() - t0
         self.cache = cache
         self._tok_dev = next_tok     # next step's input, already on device
@@ -3250,61 +3338,63 @@ class ContinuousBatchingEngine:
         if self.vpe is not None and not tripped:
             self.vpe.profiler.record(self._axis, self._last_variant, bucket, dt)
             self.vpe.controller.on_sample(self._axis, bucket, self._last_variant)
-        share = dt / n_active
-        vocab = self.cfg.vocab_size
-        quarantine: List[int] = []
-        probe_off = probe_acc = 0
-        self._probe_tick += 1
-        probing = (self.spec_draft == "auto"
-                   and self._probe_tick % self._probe_every == 0)
-        for i, slot in enumerate(self.slots):
-            if slot.req is None or slot.prefilling:
-                continue   # free/prefilling slot decoded garbage; discard
-            t = int(toks[i])
-            if t < 0 or t >= vocab:
-                # always-on numeric validation (see _fused_decode)
-                quarantine.append(i)
-                continue
-            slot.tok = t
-            slot.pos += 1
-            slot.steps_resident += 1
-            if not step_tainted:
-                slot.clean_step_shares.append(share)
-            slot.req.out.append(t)
-            if self.proposer is not None:
-                if probing:
-                    probe_acc += self._probe_accept(i, [t])
-                    probe_off += 1
-                self.proposer.observe(i, [t])
-            self.stats.tokens_out += 1
-            self._retire_if_done(i)
-        for i in quarantine:
-            self._numeric_fault(i, "out-of-range token from decode step")
-        self._update_accept_ema(probe_off, probe_acc)
-        if fault is None and not tripped:
-            self._tick_probation()
-        if self.vpe is not None and hbucket is not None and not step_tainted:
-            # the horizon axis optimizes the per-TOKEN wall of the FULL
-            # step span (host bookkeeping + device call + replay): one
-            # step at occupancy n_active emitted n_active tokens.  Same
-            # SLO charge factor as the fused path so the two variants
-            # compete under the same objective.
-            charge = 1.0 + self.slo_weight * self._queue_pressure()
-            self.vpe.profiler.record("decode_horizon", hname, hbucket,
-                                     (time.perf_counter() - t_h) / n_active
-                                     * charge)
-            self.vpe.controller.on_sample("decode_horizon", hbucket, hname)
-        if self.vpe is not None and self._spec_off_pending is not None \
-                and not step_tainted:
-            # same off-variant feed as the fused path: one step at
-            # occupancy n_active emitted n_active tokens
-            sb, sn = self._spec_off_pending
-            charge = 1.0 + self.slo_weight * self._queue_pressure()
-            self.vpe.profiler.record("spec_draft", sn, sb,
-                                     (time.perf_counter() - t_h) / n_active
-                                     * charge)
-            self.vpe.controller.on_sample("spec_draft", sb, sn)
-        return True
+        with self._span("serve.replay", tokens=n_active):
+            share = dt / n_active
+            vocab = self.cfg.vocab_size
+            quarantine: List[int] = []
+            probe_off = probe_acc = 0
+            self._probe_tick += 1
+            probing = (self.spec_draft == "auto"
+                       and self._probe_tick % self._probe_every == 0)
+            for i, slot in enumerate(self.slots):
+                if slot.req is None or slot.prefilling:
+                    continue   # free/prefilling slot decoded garbage; discard
+                t = int(toks[i])
+                if t < 0 or t >= vocab:
+                    # always-on numeric validation (see _fused_decode)
+                    quarantine.append(i)
+                    continue
+                slot.tok = t
+                slot.pos += 1
+                slot.steps_resident += 1
+                if not step_tainted:
+                    slot.clean_step_shares.append(share)
+                slot.req.out.append(t)
+                if self.proposer is not None:
+                    if probing:
+                        probe_acc += self._probe_accept(i, [t])
+                        probe_off += 1
+                    self.proposer.observe(i, [t])
+                self.stats.tokens_out += 1
+                self._retire_if_done(i)
+            for i in quarantine:
+                self._numeric_fault(i, "out-of-range token from decode step")
+            self._update_accept_ema(probe_off, probe_acc)
+            if fault is None and not tripped:
+                self._tick_probation()
+            if self.vpe is not None and hbucket is not None \
+                    and not step_tainted:
+                # the horizon axis optimizes the per-TOKEN wall of the FULL
+                # step span (host bookkeeping + device call + replay): one
+                # step at occupancy n_active emitted n_active tokens.  Same
+                # SLO charge factor as the fused path so the two variants
+                # compete under the same objective.
+                charge = 1.0 + self.slo_weight * self._queue_pressure()
+                self.vpe.profiler.record("decode_horizon", hname, hbucket,
+                                         (time.perf_counter() - t_h) / n_active
+                                         * charge)
+                self.vpe.controller.on_sample("decode_horizon", hbucket, hname)
+            if self.vpe is not None and self._spec_off_pending is not None \
+                    and not step_tainted:
+                # same off-variant feed as the fused path: one step at
+                # occupancy n_active emitted n_active tokens
+                sb, sn = self._spec_off_pending
+                charge = 1.0 + self.slo_weight * self._queue_pressure()
+                self.vpe.profiler.record("spec_draft", sn, sb,
+                                         (time.perf_counter() - t_h) / n_active
+                                         * charge)
+                self.vpe.controller.on_sample("spec_draft", sb, sn)
+            return True
 
     def run(self, max_steps: Optional[int] = None) -> List[Request]:
         """Drain queue + slots; returns completed requests."""
