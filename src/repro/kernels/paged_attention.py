@@ -1,46 +1,54 @@
 """Paged attention — block-indirect KV reads for the serve engine.
 
-Two kernels share the scalar-prefetch block-table indirection:
-:func:`paged_attention_pallas` (single-query decode, PR 3) and
+Two kernels read the page pool through a block table:
+:func:`paged_attention_pallas` (single-query decode) and
 :func:`paged_prefill_attention_pallas` (multi-query chunked prefill —
-a whole Q-chunk scored per page, per-row causal frontiers).  Decode is
-the C == 1 special case of the prefill kernel's math; they are kept
-separate because their scratch shapes and sparsity patterns differ.
+a whole Q-chunk scored per page, per-row causal frontiers).
 
 The paged KV layout (models/kvcache.py, runtime/serve_loop.py) stores
 every sequence as a *block table* of page ids into one shared pool, so
 that prefix-cache admission can alias cached pages instead of copying
-them.  Its decode read is this kernel's job: attend one query position
-per sequence against that sequence's pages, **in place** — the page id
-indirection happens in the BlockSpec index map, so no (B, T, D)
+them.  Both kernels read those pages **in place**: no (B, T, D)
 linearized copy of the KV ever exists in HBM.  (The jnp data path the
 engine uses on CPU — ``kvcache.paged_gather_layer`` + the stock decode
-attention — materializes exactly that copy; this kernel is what removes
-it on a real TPU.)
+attention — materializes exactly that copy; these kernels are what
+remove it on a real TPU.)
 
-Mechanics:
+Decode (:func:`_pa_kernel`) runs one grid step per sequence:
 
 * the block table and per-sequence lengths ride in as **scalar
-  prefetch** operands (``pltpu.PrefetchScalarGridSpec``): they are
-  available before the body runs, which is what lets the K/V BlockSpec
-  index maps compute ``page = block_table[b, j]`` and DMA the right
-  page of the pool for grid step ``(b, h, j)``;
-* grid (B, Hkv, nb) with the page dimension innermost and sequential
-  ("arbitrary"), so the online-softmax running max / denominator /
-  accumulator live in VMEM scratch across pages — the same recurrence
-  as kernels/flash_attention.py, with GQA expressed by loading all
-  ``Hq // Hkv`` query heads of a KV head per step;
-* pages past ``lengths[b]`` are masked; whole pages outside the causal
-  or sliding-window range are skipped via ``pl.when`` (block sparsity —
-  for SWA archs only O(window / page_size) pages are touched).
+  prefetch** operands (``pltpu.PrefetchScalarGridSpec``); the K/V pools
+  stay in HBM (``pl.ANY``) and the body copies pages itself;
+* the step visits only the **live pages**, ``first .. length // bs``
+  (``first`` is the sliding window's first page, else 0): a loop with a
+  dynamic trip count, so a dead page costs no grid step, copy or
+  compute;
+* a page is **one copy of all the pool's KV heads** (``pool.at[page]``,
+  a contiguous (Hkv, bs, D) block), and ``P = 128 // bs`` pages make a
+  block whose scores fill a 128-lane tile; the next block's copies are
+  in flight while one computes (two buffer slots);
+* **K and V are read once each**: the K pass writes each block's
+  masked f32 scores into VMEM (at most ``nb * bs`` columns per query
+  head) and keeps the running max; the denominator is then summed from
+  those scores, exactly as ``jax.nn.softmax`` forms it; the V pass
+  forms ``p`` against those final statistics and accumulates ``p @ v``
+  for all ``Hq // Hkv`` query heads of each KV head.
 
-Numerics: f32 accumulation throughout, validated against
-``kernels.ref.paged_attention_ref`` (which is itself exact vs the
-contiguous decode attention on identically-valued pages).  With
-``read_dtype`` set, decode switches to a two-phase body
-(:func:`_pa_kernel_quantized`) that reproduces the serve gather path's
-bf16 quantization of both the KV reads and the softmax probabilities —
-see that kernel's docstring for why a single online pass can't.
+Numerics: f32 scores, statistics and accumulation, validated against
+``kernels.ref.paged_attention_ref`` (itself exact vs the contiguous
+decode attention on identically-valued pages).  With ``read_dtype``
+set, K, V and the normalized probabilities are rounded through it
+before the products — the serve gather path's two casts
+(``paged_gather_layer(..., out_dtype=SLOT_CACHE_DTYPE)``, then
+``p.astype(v.dtype)`` in ``decode_attention``), which keep the two
+decode backends token-parity-exact.  A post-normalization cast of ``p``
+needs the final max and denominator before any value product; keeping
+the scores in VMEM gives them without reading K twice.
+
+Prefill keeps a grid (B, Hkv, nb) with the page dimension innermost and
+sequential, an online softmax across pages in VMEM scratch (the same
+recurrence as kernels/flash_attention.py), and skips whole pages
+outside the causal or sliding-window range via ``pl.when``.
 """
 
 from __future__ import annotations
@@ -60,135 +68,124 @@ _NEG_INF = float("-inf")
 
 def _pa_kernel(
     bt_ref, len_ref,            # scalar prefetch: (B, nb) pages, (B,) lengths
-    q_ref, k_ref, v_ref, o_ref,
-    m_ref, l_ref, acc_ref,
-    *, bs: int, nb: int, window: Optional[int], scale: float,
-    read_dtype=None,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-    col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
-
-    def body():
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, 0]                                # (bs, D)
-        v = v_ref[0, 0]
-        if read_dtype is not None:
-            # round-trip through the slot-cache dtype so the kernel sees
-            # exactly the values the gather path reads (parity contract:
-            # paged_gather_layer(..., out_dtype=SLOT_CACHE_DTYPE))
-            k = k.astype(read_dtype)
-            v = v.astype(read_dtype)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                      # (G, bs)
-        mask = col <= length                           # causal incl. self
-        if window is not None:
-            mask &= col > length - window
-        s = jnp.where(mask[None, :], s, _NEG_INF)
-        m_prev = m_ref[...]                            # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(m_new == _NEG_INF, 0.0, jnp.exp(s - m_new))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    # block sparsity: skip pages entirely past the causal frontier (and,
-    # for SWA, entirely before the window)
-    live = j * bs <= length
-    if window is not None:
-        live &= (j * bs + bs - 1) > length - window
-    pl.when(live)(body)
-
-    @pl.when(j == nb - 1)
-    def _flush():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows -> 0 output
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _pa_kernel_quantized(
-    bt_ref, len_ref,            # scalar prefetch: (B, nb) pages, (B,) lengths
-    q_ref, k_ref, v_ref, o_ref,
-    m_ref, l_ref, acc_ref,
-    *, bs: int, nb: int, window: Optional[int], scale: float,
+    q_ref, k_hbm, v_hbm,        # q block (1, Hkv, G, D); pools (N, Hkv, bs, D)
+    o_ref,
+    buf, sems, s_ref, m_ref, l_ref, acc_ref,
+    *, bs: int, nb: int, P: int, window: Optional[int], scale: float,
     read_dtype,
 ):
-    """Two-phase decode body reproducing the gather path's value-matmul
-    quantization (``decode_attention`` casts the softmax probabilities
-    to the cache dtype before the value einsum — a post-normalization
-    cast an online softmax cannot mirror blockwise, since the final
-    max/denominator aren't known mid-stream).  Phase 0 (grid steps
-    ``0..nb-1``) runs the online recurrence for the final stats only;
-    phase 1 (``nb..2nb-1``) re-scores each page against those FINAL
-    stats and accumulates ``dot(read_dtype(p), read_dtype(v))`` — the
-    exact softmax-then-cast the jnp path computes, page-blocked.  Decode
-    is one query row per head group, so the second score pass is noise
-    next to the page DMAs it rides."""
+    """One slot's decode: stream its live pages through VMEM, K then V.
+
+    Stream item ``i`` is block ``i`` of K for ``i < n``, block ``i - n``
+    of V after; a block is ``P`` consecutive pages of the live range,
+    each copied for all of the shard's KV heads at once.  Item ``i + 1``
+    is in flight while item ``i`` computes (two buffer slots).  The K
+    pass writes the masked scores to ``s_ref`` and keeps the running
+    max; the exact denominator is summed from ``s_ref``; the V pass
+    forms ``p`` against those final statistics."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    jj = jax.lax.rem(j, nb)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
+    Hkv = buf.shape[2]
+    W = P * bs
     length = len_ref[b]
-    col = jj * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)[0]
+    last = jnp.minimum(length // bs, nb - 1)
+    first = 0 if window is None else jnp.maximum(
+        (length - window + 1) // bs, 0)
+    n = (jnp.maximum(last - first + 1, 0) + P - 1) // P
 
-    def scores():
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        k = k_ref[0, 0].astype(read_dtype).astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                      # (G, bs)
-        mask = col <= length
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def live_pages(k):
+        """Pages of block ``k`` inside the live range: its first ones."""
+        return jnp.clip(last + 1 - (first + k * P), 0, P)
+
+    def for_pages(lo, hi, body):
+        def step(p, carry):
+            body(p)
+            return carry
+        jax.lax.fori_loop(lo, hi, step, 0)
+
+    def copy(pool, k, slot, p):
+        page = bt_ref[b, first + k * P + p]
+        return pltpu.make_async_copy(pool.at[page], buf.at[slot, p],
+                                     sems.at[slot])
+
+    def start(i):
+        for pool, lo, hi in ((k_hbm, 0, n), (v_hbm, n, 2 * n)):
+            @pl.when((i >= lo) & (i < hi))
+            def _():
+                for_pages(0, live_pages(i - lo),
+                          lambda p: copy(pool, i - lo, i % 2, p).start())
+
+    def wait(pool, k, slot):
+        for_pages(0, live_pages(k), lambda p: copy(pool, k, slot, p).wait())
+
+    def cols(k):
+        col = (first + k * P) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (1, W), 1)
+        # a length past the table (a free slot's stale one) reads it whole
+        valid = (col <= length) & (col < (last + 1) * bs)
         if window is not None:
-            mask &= col > length - window
-        return jnp.where(mask[None, :], s, _NEG_INF)
+            valid &= col > length - window
+        return valid
 
-    def stats_pass():
-        s = scores()
-        m_prev = m_ref[...]                            # (G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.where(m_prev == _NEG_INF, 0.0, jnp.exp(m_prev - m_new))
-        p = jnp.where(m_new == _NEG_INF, 0.0, jnp.exp(s - m_new))
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = m_new
+    def page_block(slot, h):
+        x = buf[slot, :, h].reshape(W, buf.shape[-1])      # (P*bs, D)
+        return x if read_dtype is None else x.astype(read_dtype)
 
-    def value_pass():
-        s = scores()
-        m = m_ref[...]
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows -> 0 output
-        p = jnp.where(m == _NEG_INF, 0.0, jnp.exp(s - m)) / l
-        p = p.astype(read_dtype).astype(jnp.float32)
-        v = v_ref[0, 0].astype(read_dtype).astype(jnp.float32)
-        acc_ref[...] += jax.lax.dot(p, v, preferred_element_type=jnp.float32)
+    def dot(a, x, dims):
+        # bf16 x bf16 products are exact in f32: the MXU's native pass
+        # gives what f32 operands holding the same values would
+        ct = jnp.promote_types(a.dtype, x.dtype)
+        return jax.lax.dot_general(
+            a.astype(ct), x.astype(ct), (dims, ((), ())),
+            preferred_element_type=jnp.float32)
 
-    live = jj * bs <= length
-    if window is not None:
-        live &= (jj * bs + bs - 1) > length - window
-    pl.when(live & (j < nb))(stats_pass)
-    pl.when(live & (j >= nb))(value_pass)
+    def probs(k, h):
+        s = s_ref[h, :, pl.ds(pl.multiple_of(k * W, W), W)]
+        m = m_ref[h]
+        return jnp.where(m == _NEG_INF, 0.0, jnp.exp(s - m))
 
-    @pl.when(j == 2 * nb - 1)
-    def _flush():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)   # pre-normalized
+    def k_block(k, carry):
+        start(k + 1)
+        slot = k % 2
+        wait(k_hbm, k, slot)
+        valid = cols(k)
+        for h in range(Hkv):
+            s = dot(q_ref[0, h], page_block(slot, h), ((1,), (1,))) * scale
+            s = jnp.where(valid, s, _NEG_INF)                  # (G, P*bs)
+            s_ref[h, :, pl.ds(pl.multiple_of(k * W, W), W)] = s
+            m_ref[h] = jnp.maximum(m_ref[h], jnp.max(s, axis=1, keepdims=True))
+        return carry
+
+    def denominator(k, carry):
+        for h in range(Hkv):
+            l_ref[h] += jnp.sum(probs(k, h), axis=1, keepdims=True)
+        return carry
+
+    def v_block(k, carry):
+        start(n + k + 1)
+        slot = (n + k) % 2
+        wait(v_hbm, k, slot)
+        # a page past the live range was not copied: zero its stale rows,
+        # whose p is 0 but which may hold anything
+        def clear(p):
+            buf[slot, p] = jnp.zeros(buf.shape[2:], buf.dtype)
+        for_pages(live_pages(k), P, clear)
+        for h in range(Hkv):
+            l = l_ref[h]
+            p = probs(k, h) / jnp.where(l == 0.0, 1.0, l)
+            if read_dtype is not None:
+                p = p.astype(read_dtype)
+            acc_ref[h] += dot(p, page_block(slot, h), ((1,), (0,)))
+        return carry
+
+    start(0)
+    jax.lax.fori_loop(0, n, k_block, 0)
+    jax.lax.fori_loop(0, n, denominator, 0)
+    jax.lax.fori_loop(0, n, v_block, 0)
+    o_ref[0] = acc_ref[...].astype(o_ref.dtype)   # fully masked rows -> 0
 
 
 def _pa_prefill_kernel(
@@ -199,9 +196,9 @@ def _pa_prefill_kernel(
     *, bs: int, nb: int, C: int,
     window: Optional[int], scale: float,
 ):
-    """Multi-query (chunked-prefill) body: identical online-softmax
-    recurrence to :func:`_pa_kernel`, but every grid step scores a whole
-    chunk of ``C`` queries — rows of the (G*C, bs) score tile are query
+    """Multi-query (chunked-prefill) body: the online-softmax recurrence
+    of kernels/flash_attention.py over pages, every grid step scoring a
+    whole chunk of ``C`` queries — rows of the (G*C, bs) score tile are query
     (g, i) at absolute position ``base + i``, so the causal frontier is
     per ROW rather than per sequence.  The chunk's own K/V are read from
     the pages like everything else (the engine writes-then-attends),
@@ -290,8 +287,9 @@ def paged_prefill_attention_pallas(
     serve engine's chunk jits and fused ``lax.scan`` bodies never
     recompile on it).  Returns (B, Hq, C, D).
 
-    Same scalar-prefetch indirection as :func:`paged_attention_pallas`
-    (grid step (b, h, j) DMAs pool page ``block_tables[b, j]``), with
+    The block table rides in as scalar prefetch, as for
+    :func:`paged_attention_pallas`; grid step (b, h, j) DMAs pool page
+    ``block_tables[b, j]`` through the K/V BlockSpec index maps, with
     all ``Hq // Hkv * C`` query rows of a KV head scored per page — the
     multi-query generalization the chunked-prefill engine path needs,
     so a 32k prompt's prefill touches each page once per chunk instead
@@ -367,19 +365,18 @@ def paged_attention_pallas(
 
     ``interpret`` defaults to true everywhere except a real TPU backend
     (``compat.default_interpret``); interpret mode runs the kernel body
-    as stock jax ops — traceable under jit/scan — while on TPU the
-    indirect BlockSpec turns into per-page DMA.
+    as stock jax ops — traceable under jit/scan — while on TPU each
+    live page becomes one DMA of all the pool's KV heads.
 
     ``read_dtype`` (static) makes the kernel reproduce the gather
     path's quantization semantics end to end: K/V pages are
-    round-tripped through that dtype before the f32 compute (the values
+    round-tripped through that dtype (the values
     ``paged_gather_layer(..., out_dtype=SLOT_CACHE_DTYPE)`` reads), and
-    the body switches to the two-phase :func:`_pa_kernel_quantized` so
-    the softmax probabilities are ALSO cast through it before the value
+    the softmax probabilities are cast through it before the value
     matmul — the ``p.astype(v.dtype)`` in ``decode_attention``.  Both
     casts are what keeps the two decode backends token-parity-exact;
     omitting either leaves a ~4e-3 logit gap that flips greedy tokens
-    over long generations.
+    over long generations.  Without it ``p`` stays in f32.
     """
     B, Hq, S, D = q.shape
     N, Hkv, bs, _ = k_pool.shape
@@ -391,49 +388,38 @@ def paged_attention_pallas(
         interpret = default_interpret()
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    # a block of P pages scores P * bs keys: one 128-lane tile at bs <= 128
+    P = max(1, 128 // bs)
+    n_cols = pl.cdiv(nb, P) * P * bs
     q4 = q.reshape(B, Hkv, group, D)
-    if read_dtype is not None:
-        kernel = functools.partial(
-            _pa_kernel_quantized, bs=bs, nb=nb, window=window, scale=scale,
-            read_dtype=read_dtype)
-        grid = (B, Hkv, 2 * nb)   # stats pass, then quantized value pass
-
-        def page(bt, b, j):
-            return bt[b, jax.lax.rem(j, nb)]
-    else:
-        kernel = functools.partial(
-            _pa_kernel, bs=bs, nb=nb, window=window, scale=scale,
-            read_dtype=None)
-        grid = (B, Hkv, nb)
-
-        def page(bt, b, j):
-            return bt[b, j]
+    kernel = functools.partial(
+        _pa_kernel, bs=bs, nb=nb, P=P, window=window, scale=scale,
+        read_dtype=read_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), lambda b, h, j, bt, ln: (b, h, 0, 0)),
-            # the paged read: grid step (b, h, j) DMAs pool page
-            # block_tables[b, j mod nb] — indirection via scalar prefetch
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, j, bt, ln: (page(bt, b, j), h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, D),
-                         lambda b, h, j, bt, ln: (page(bt, b, j), h, 0, 0)),
+            pl.BlockSpec((1, Hkv, group, D), lambda b, bt, ln: (b, 0, 0, 0)),
+            # the pools stay in HBM: the body copies the live pages
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, D), lambda b, h, j, bt, ln: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, group, D),
+                               lambda b, bt, ln: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((2, P, Hkv, bs, D), k_pool.dtype),   # page blocks
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((Hkv, group, n_cols), jnp.float32),  # scores
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),       # max
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),       # denominator
+            pltpu.VMEM((Hkv, group, D), jnp.float32),       # p @ v
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(block_tables, lengths, q4, k_pool, v_pool)
     return out.reshape(B, Hq, 1, D)

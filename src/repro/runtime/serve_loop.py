@@ -883,6 +883,9 @@ class ContinuousBatchingEngine:
         self.queue: List[Request] = []
         self.completed: List[Request] = []
         self.slots = [_Slot() for _ in range(slots)]
+        # host mirror of the device's cache["length"]: unlike a slot's
+        # pos it keeps a freed slot's stale value, as the device does
+        self._dev_len = np.zeros((slots,), np.int64)
         self._decode_fns: Dict[str, Callable] = {}
         self._axis = "serve_decode_impl"
         self._default_variant = SERVE_AXES[self._axis][0]
@@ -1603,14 +1606,25 @@ class ContinuousBatchingEngine:
                       ) -> Dict[str, int]:
         """``serve.decode``'s stats: the decoding slots in the call, its
         horizon, the keys its first token attends to and the pages the
-        rows' block tables hold, each summed over the rows."""
+        rows' block tables hold, each summed over the rows; with a page
+        pool, ``walked``: the pages the paged decode kernel copies for
+        the call's first token, summed over every slot (it bounds its
+        walk by the device length alone, so free and frozen slots count
+        with their stale lengths)."""
         n = keys = pages = 0
         for i in rows:
             slot = self.slots[i]
             n += 1
             keys += slot.pos + 1
             pages += len(slot.pages)
-        return {"rows": n, "horizon": horizon, "keys": keys, "pages": pages}
+        stats = {"rows": n, "horizon": horizon, "keys": keys, "pages": pages}
+        if self.pages is not None:
+            bs, length = self.block_size, self._dev_len
+            last = np.minimum(length // bs, self.nb_max - 1)
+            first = (0 if self.cfg.window is None else
+                     np.maximum((length - self.cfg.window + 1) // bs, 0))
+            stats["walked"] = int(np.maximum(last - first + 1, 0).sum())
+        return stats
 
     def _guarded_fence(self, value, fault) -> Tuple[np.ndarray, bool]:
         """Fence a decode span's token output, through the watchdog when
@@ -2227,6 +2241,7 @@ class ContinuousBatchingEngine:
         S = len(req.prompt) + len(req.out)
         first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
         self.cache = self._set_len(self.cache, jnp.int32(i), jnp.int32(S))
+        self._dev_len[i] = S
         slot.admit_wall = slot.place_wall + sum(slot.chunk_walls)
         if self.vpe is not None and not slot.tainted:
             if slot.reuse_bucket is not None:
@@ -2298,6 +2313,7 @@ class ContinuousBatchingEngine:
         self._wait(k)
         t0 = time.perf_counter()
         self.cache = self._insert(self.cache, k, v, jnp.int32(i), jnp.int32(S))
+        self._dev_len[i] = S
         self._wait(self.cache)
         self.stats.kv_place_s.append(time.perf_counter() - t0)
         first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
@@ -2339,6 +2355,7 @@ class ContinuousBatchingEngine:
             self.params, jnp.asarray(toks), pk, pv, jnp.int32(P), jnp.int32(sl))
         self.cache = self._insert_at(cache, k, v, jnp.int32(i), jnp.int32(P),
                                      jnp.int32(S))
+        self._dev_len[i] = S
         first = int(self._wait(jnp.argmax(logits[0]), np.asarray))
         return first, k, v, P
 
@@ -2366,6 +2383,7 @@ class ContinuousBatchingEngine:
         self.cache = self._admit_paged(self.cache,
                                        jnp.asarray(self._bt_row(pages)),
                                        jnp.int32(i), jnp.int32(true_len))
+        self._dev_len[i] = true_len
         self.slots[i].pages = list(pages)
 
     def _suffix_page_ids(self, base: int, S: int, cow_page: Optional[int],
@@ -2827,6 +2845,7 @@ class ContinuousBatchingEngine:
             emits = self._wait(valid, np.asarray)
         dt = time.perf_counter() - t0
         self.cache = cache
+        self._dev_len += emits.sum(axis=1)
         self._tok_dev = final_tok
         self.stats.decode_s += dt
         self.stats.decode_steps += H
@@ -3083,6 +3102,7 @@ class ContinuousBatchingEngine:
             emits = self._wait(valid, np.asarray)
         dt = time.perf_counter() - t0
         self.cache = cache
+        self._dev_len += emits.sum(axis=1)
         self._tok_dev = final_tok
         self.stats.decode_s += dt
         self.stats.decode_steps += 1
@@ -3308,6 +3328,7 @@ class ContinuousBatchingEngine:
             toks, tripped = self._guarded_fence(next_tok, fault)
         dt = time.perf_counter() - t0
         self.cache = cache
+        self._dev_len += 1
         self._tok_dev = next_tok     # next step's input, already on device
         self.stats.decode_s += dt
         self.stats.decode_steps += 1

@@ -123,17 +123,36 @@ class TestPagedAttention:
         vp = rng.standard_normal((N, Hkv, bs, D)).astype(np.float32)
         return jnp.asarray(kp), jnp.asarray(vp)
 
+    # poisoned cases: lengths at the page edges (0, bs-1, bs, nb*bs-1),
+    # every page outside a row's live range NaN, in both read modes
+    EDGES = dict(B=6, Hq=4, Hkv=2, bs=8, nb=4, D=32,
+                 lengths=(0, 7, 8, 31, 13, 20))
+
     @pytest.mark.parametrize("case", [
         dict(B=3, Hq=4, Hkv=2, bs=8, nb=4, D=32, window=None),   # GQA
         dict(B=2, Hq=4, Hkv=2, bs=8, nb=6, D=32, window=9),      # SWA
         dict(B=2, Hq=4, Hkv=4, bs=16, nb=3, D=16, window=None),  # MHA
         dict(B=1, Hq=8, Hkv=1, bs=4, nb=8, D=64, window=None),   # MQA
+        dict(EDGES, window=None, poison=True),
+        dict(EDGES, window=None, poison=True, read_dtype=jnp.bfloat16),
+        dict(EDGES, window=9, poison=True),
+        dict(EDGES, window=9, poison=True, read_dtype=jnp.bfloat16),
+        dict(B=4, Hq=8, Hkv=1, bs=4, nb=8, D=64, window=None,    # MQA
+             lengths=(0, 3, 4, 31), poison=True),
     ])
     def test_against_oracle(self, case):
+        """The kernel equals the gather oracle.  Poisoned cases fill
+        every page no row reads with NaN (past a row's length, before
+        its sliding window's first page, the trash page repeated in the
+        tables' tails): the kernel must not touch them, so its output is
+        finite and equals the oracle over the clean pool.  With
+        ``read_dtype`` the kernel also rounds K, V and the probabilities
+        to bfloat16, which the tolerance admits."""
         from repro.kernels.paged_attention import paged_attention_pallas
         rng = np.random.default_rng(0)
         B, Hq, Hkv, bs, nb, D = (case[k]
                                  for k in ("B", "Hq", "Hkv", "bs", "nb", "D"))
+        window, read_dtype = case["window"], case.get("read_dtype")
         N = nb * B
         kp, vp = self._pool(rng, N, Hkv, bs, D)
         q = jnp.asarray(rng.standard_normal((B, Hq, 1, D)).astype(np.float32))
@@ -141,14 +160,48 @@ class TestPagedAttention:
         bt = rng.integers(0, N, (B, nb)).astype(np.int32)
         bt[1:, 0] = bt[0, 0]
         lengths = rng.integers(0, nb * bs, (B,)).astype(np.int32)
-        got = paged_attention_pallas(q, kp, vp, jnp.asarray(bt),
-                                     jnp.asarray(lengths),
-                                     window=case["window"])
+        dead = None
+        if case.get("poison"):
+            lengths = np.asarray(case["lengths"], np.int32)
+            bt, live = self._live_tables(rng, lengths, nb, bs, window)
+            N = bt.max() + 1
+            kp, vp = self._pool(rng, N, Hkv, bs, D)
+            dead = jnp.asarray(~np.isin(np.arange(N), live))[:, None, None,
+                                                              None]
+        if read_dtype is not None:   # values the bf16 read keeps exactly
+            kp, vp = (x.astype(read_dtype).astype(jnp.float32)
+                      for x in (kp, vp))
+        k_in, v_in = ((kp, vp) if dead is None else
+                      (jnp.where(dead, jnp.nan, x) for x in (kp, vp)))
+        got = paged_attention_pallas(q, k_in, v_in, jnp.asarray(bt),
+                                     jnp.asarray(lengths), window=window,
+                                     read_dtype=read_dtype)
         want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(bt),
-                                       jnp.asarray(lengths),
-                                       window=case["window"])
+                                       jnp.asarray(lengths), window=window)
+        assert np.isfinite(np.asarray(got)).all()
+        tol = 3e-5 if read_dtype is None else 1e-2
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=3e-5, atol=3e-5)
+                                   rtol=tol, atol=tol)
+
+    @staticmethod
+    def _live_tables(rng, lengths, nb, bs, window):
+        """Block tables whose live entries hold distinct pages, except
+        the first live page, which every row shares; the entries before
+        a window's first page hold pages of their own and every entry
+        past a row's last page holds the trash page (the largest id).
+        Returns (tables, the ids some row reads)."""
+        B = len(lengths)
+        bt = np.arange(B * nb, dtype=np.int32).reshape(B, nb)
+        rng.shuffle(bt.reshape(-1))
+        trash = B * nb
+        live = []
+        for b, n in enumerate(lengths):
+            first = 0 if window is None else max(0, (n - window + 1) // bs)
+            last = n // bs
+            bt[b, first] = bt[0, 0]
+            bt[b, last + 1:] = trash
+            live.extend(bt[b, first:last + 1])
+        return bt, np.unique(live)
 
     @pytest.mark.parametrize("case", [
         dict(B=2, Hq=4, Hkv=2, bs=8, nb=6, C=16, D=32, window=None),  # GQA
@@ -242,10 +295,10 @@ class TestPagedAttention:
         (SLOT_CACHE_DTYPE) and ``decode_attention`` additionally casts
         the softmax probabilities to the cache dtype before the value
         einsum.  With read_dtype set the kernel reproduces BOTH
-        quantizations (two-phase body: final stats first, then a
-        re-score pass that accumulates bf16(p) @ bf16(v)), so it must
-        match the real serve gather path — not just an f32 oracle over
-        pre-quantized pools — to well under the ~4e-3 gap that flipped
+        quantizations (scores kept in VMEM until the final stats are
+        known, then a V pass that accumulates bf16(p) @ bf16(v)), so it
+        must match the real serve gather path — not just an f32 oracle
+        over pre-quantized pools — to well under the ~4e-3 gap that flipped
         greedy tokens when p stayed in f32.  Exact end-to-end greedy
         parity rides on this and is proven by TestEngineKernelVariants
         below."""

@@ -144,6 +144,30 @@ def test_tokens_are_the_same_with_and_without_a_trace(params, tmp_path):
     assert [r.out for r in traced] == [r.out for r in plain]
 
 
+def test_walked_counts_each_slots_live_pages(params, tmp_path):
+    """``serve.decode``'s ``walked`` is the pages the paged decode kernel
+    copies for the call's first token: every slot's pages up to its
+    device length (capped at the table), free and frozen slots too."""
+    engine = ContinuousBatchingEngine(CFG, params, **ENGINE)
+    lengths = []
+    stats = engine._decode_stats
+
+    def read_lengths_first(rows, horizon):
+        lengths.append(np.asarray(engine.cache["length"]))
+        return stats(rows, horizon)
+
+    engine._decode_stats = read_lengths_first
+    serve_traced(engine, make_requests(), tmp_path)
+    walked = [st["walked"] for name, _, _, st in read_spans(tmp_path)
+              if name == "serve.decode"]
+    bs = ENGINE["block_size"]
+    nb = ENGINE["max_len"] // bs
+    assert len(walked) == len(lengths) > 0
+    assert walked == [int((np.minimum(n // bs, nb - 1) + 1).sum())
+                      for n in lengths]
+    assert max(walked) < ENGINE["slots"] * nb
+
+
 _MESH = r"""
 import json, sys
 sys.path.insert(0, "tests")

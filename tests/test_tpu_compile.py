@@ -5,8 +5,10 @@ sees what the chip's compiler refuses: a slice not aligned to the
 tiling, more scoped VMEM than a kernel may use, a grid the Mosaic
 lowering cannot express.  These tests compile each kernel natively
 (``interpret=False``) for a described, unattached v5e chip at the widths
-the serving path runs (qwen3-8b: 32 query and 8 KV heads of 128) and
-check that the compiled program holds the kernel (``tpu_custom_call``).
+the serving path runs (qwen3-8b: 32 query and 8 KV heads of 128; the
+decode kernel also at qwen2-7b's 28 and 4 heads and at a four-way
+tensor shard's 8 and 2) and check that the compiled program holds the
+kernel (``tpu_custom_call``).
 Nothing runs, so they say nothing about results or speed.
 
 The topology is described inside a module-scoped fixture, never while
@@ -73,14 +75,28 @@ def _i32(sharding, *shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
 
+# decode widths of the benchmark's cells and of a tensor shard: (slots,
+# pages per slot, query heads, KV heads)
+DECODE_WIDTHS = {
+    "qwen3-8b": (24, 192, 32, 8),
+    "qwen2-7b": (16, 384, 28, 4),
+    "qwen3-8b-tp4-shard": (24, 192, 8, 2),
+}
+
+
+@pytest.mark.parametrize("widths", list(DECODE_WIDTHS.values()),
+                         ids=list(DECODE_WIDTHS))
 @pytest.mark.parametrize("read_dtype", [None, jnp.bfloat16],
-                         ids=["online", "two_pass"])
-def test_paged_decode_attention(one_chip, read_dtype):
-    q = jax.ShapeDtypeStruct((B, HQ, 1, D), jnp.bfloat16, sharding=one_chip)
+                         ids=["f32_probs", "bf16_probs"])
+def test_paged_decode_attention(one_chip, read_dtype, widths):
+    b, nb, hq, hkv = widths
+    q = jax.ShapeDtypeStruct((b, hq, 1, D), jnp.bfloat16, sharding=one_chip)
+    pool = jax.ShapeDtypeStruct((b * nb + 1, hkv, BS, D), jnp.bfloat16,
+                                sharding=one_chip)
     fn = functools.partial(paged_attention_pallas, interpret=False,
                            read_dtype=read_dtype)
-    text = _compiled_text(fn, q, _pool(one_chip), _pool(one_chip),
-                          _i32(one_chip, B, NB), _i32(one_chip, B))
+    text = _compiled_text(fn, q, pool, pool, _i32(one_chip, b, nb),
+                          _i32(one_chip, b))
     assert "tpu_custom_call" in text
 
 
