@@ -12,6 +12,11 @@ logit.  A right token scores 0 or a rounding-sized gap at a near-tie; a
 token from a broken cache, kernel or position scores like a random
 vocabulary entry, several units down.
 
+On a mesh the weights stay sharded as the serving engine keeps them:
+XLA partitions each of the reference's jitted pieces (a layer, the
+head) over the weights' shardings, adding the partial sums of the split
+matrix products, so no chip ever holds the whole model.
+
 The control (:func:`control_gap`, run by ``bench/calibrate.py`` and the
 tests, never by a benchmark run) puts the reference itself in the
 program's place, computed a precision below the configuration's, and

@@ -12,11 +12,12 @@ configuration (``bench/configs/<config>.json``), traffic mix
 
 One process, one thread:
 
-1. set-up: weights made on the device from the seed in one program,
-   the serving engine built as a user builds it
-   (``make_serve_engine``), every program shape the traffic can use
-   warmed by set-up traffic, and for a backlog the ramp to full slots
-   in steady state (``traffic.steady_start``);
+1. set-up: weights made on the device from the seed in one program
+   (on a mesh, each chip makes its own shards), the serving engine
+   built as a user builds it (``make_serve_engine``), every program
+   shape the traffic can use warmed by set-up traffic, and for a
+   backlog the ramp to full slots in steady state
+   (``traffic.steady_start``);
 2. the window: ``--seconds`` of traffic (``bench/drive.py``), with
    compilations counted (there should be none); scheduled traffic then
    drains the requests due in the window;
@@ -42,6 +43,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import itertools  # noqa: E402
@@ -133,18 +135,41 @@ HF_KEYS = {
 
 
 def model_config(config: Dict):
-    """The program's configuration object for a configuration file."""
+    """The program's configuration object for a configuration file: the
+    registry's entry for its ``model`` with every ``HF_KEYS`` key of the
+    file, the architecture's ``qk_norm`` and ``qkv_bias``, and last any
+    field of ``ModelConfig`` that the file's optional ``program`` object
+    names.  A key of ``HF_KEYS`` may be left out of the file only where
+    the ``program`` object gives its field, so that every departure from
+    the published file is written down in it."""
     from repro.configs import get_config
-    fields = {dst: config[src] for src, dst in HF_KEYS.items()}
+    base = get_config(config["model"])
+    program = config.get("program", {})
+    unknown = sorted(set(program) - {f.name for f in dataclasses.fields(base)})
+    if unknown:
+        raise BenchError(f"the configuration's program object names no "
+                         f"field of ModelConfig: {unknown}")
+    missing = sorted(src for src, dst in HF_KEYS.items()
+                     if src not in config and dst not in program)
+    if missing:
+        raise BenchError(f"the configuration file lacks {missing}, and its "
+                         f"program object gives no field for them")
+    fields = {dst: config[src] for src, dst in HF_KEYS.items()
+              if src in config}
     arch = config.get("architecture", {})
     fields["qk_norm"] = bool(arch.get("qk_norm", False))
     fields["qkv_bias"] = bool(arch.get("qkv_bias", False))
-    return dataclasses.replace(get_config(config["model"]), **fields)
+    fields.update(program)
+    return dataclasses.replace(base, **fields)
 
 
-def make_weights(ref, dims: Dict, mcfg, seed: int):
+def make_weights(ref, dims: Dict, mcfg, seed: int,
+                 mesh_shape: Sequence[int]):
     """The reference's weights from the seed, in one program on the
-    device, after checking that the program expects this layout."""
+    device, after checking that the program expects this layout.  On a
+    mesh of more than one device every leaf is made under the sharding
+    that the serving engine keeps for it on ``serve_mesh(dp, mp)``, so
+    each chip creates only its own shard and the engine moves nothing."""
     from repro.models import model as model_lib
     want = {tuple(k.key for k in path): tuple(leaf.shape)
             for path, leaf in jax.tree_util.tree_flatten_with_path(
@@ -155,7 +180,17 @@ def make_weights(ref, dims: Dict, mcfg, seed: int):
                          f"reference's: {sorted(set(want) ^ set(have))}")
     words = traffic.seed_sequence(seed, traffic.WEIGHTS).generate_state(2)
     key = jax.random.wrap_key_data(jax.numpy.asarray(words, jax.numpy.uint32))
-    params = jax.jit(lambda k: ref.init(dims, k))(key)
+    if tuple(mesh_shape) == (1, 1):
+        # one device: weights under a one-device mesh's sharding made the
+        # engine compile 4-5 more programs in set-up and 1-2 inside the
+        # measured window on a TPU v5e chip
+        params = jax.jit(lambda k: ref.init(dims, k))(key)
+    else:
+        from repro.distributed import sharding
+        mesh = sharding.serve_mesh(*mesh_shape)
+        init = functools.partial(ref.init, dims)
+        placed = sharding.param_shardings(jax.eval_shape(init, key), mesh)
+        params = jax.jit(init, out_shardings=placed)(key)
     jax.block_until_ready(params)
     return params
 
@@ -183,9 +218,12 @@ class CompileCounter:
 
 
 def peak_bytes(devices) -> Optional[int]:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in devices]
-    peaks = [p for p in peaks if p is not None]
+    """Peak device memory on the fullest device: the peak of arrays plus
+    the peak held for the programs' temporaries, which a TPU reserves
+    apart and ``peak_bytes_in_use`` leaves out."""
+    peaks = [(d.memory_stats() or {}) for d in devices]
+    peaks = [s["peak_bytes_in_use"] + s.get("peak_bytes_reserved", 0)
+             for s in peaks if "peak_bytes_in_use" in s]
     return max(peaks) if peaks else None
 
 
@@ -254,7 +292,7 @@ def set_up(config: Dict, seed: int):
     dims = ref.dims(config)
     mcfg = model_config(config)
     eng = dict(config["engine"])
-    params = make_weights(ref, dims, mcfg, seed)
+    params = make_weights(ref, dims, mcfg, seed, config["mesh"])
     engine = make_serve_engine(mcfg, params, mesh_shape=tuple(config["mesh"]),
                                **eng)
     client = drive.Client(engine, Request, dims["V"], seed)

@@ -4,14 +4,18 @@ traffic made from the seed alone, metrics over the whole window, and
 the refusal to run without a TPU."""
 
 import dataclasses
+import hashlib
 import os
 import re
 import subprocess
 import sys
 
+import jax
+import numpy as np
 import pytest
 
 from bench import check, drive, measured, run, traffic
+from repro.configs import get_config
 
 ROOT = run.ROOT
 BENCH = run.benchmark()
@@ -72,6 +76,116 @@ def test_per_layer_metrics_move_an_end_to_end_metric_of_their_cells():
         moved = e2e[m["moves"]]
         for w in m["workloads"]:
             assert w in moved.get("workloads", [w])
+
+
+# the files written before a configuration could carry a ``program``
+# object (a later file may, and may then leave out the keys it gives)
+@pytest.mark.parametrize("name", ["qwen3-8b", "qwen2-7b", "qwen3-8b-tp4"])
+def test_configuration_files_map_as_every_key_was_required(name):
+    """With every ``HF_KEYS`` key in the file and no ``program`` object,
+    the mapping is the one that required every key."""
+    cfg = run.load_json(ROOT / "bench" / "configs" / f"{name}.json")
+    arch = cfg["architecture"]
+    want = dataclasses.replace(
+        get_config(cfg["model"]),
+        **{dst: cfg[src] for src, dst in run.HF_KEYS.items()},
+        qk_norm=bool(arch["qk_norm"]), qkv_bias=bool(arch["qkv_bias"]))
+    assert "program" not in cfg
+    assert run.model_config(cfg) == want
+
+
+def _qwen3():
+    return run.load_json(ROOT / "bench" / "configs" / "qwen3-8b.json")
+
+
+def test_a_program_object_sets_a_field_of_the_program_config():
+    cfg = _qwen3()
+    cfg["program"] = {"window": 1024, "rope_theta": 5e4}
+    got = run.model_config(cfg)
+    assert got.window == 1024 and got.rope_theta == 5e4
+    assert dataclasses.replace(got, window=None,
+                               rope_theta=1e6) == run.model_config(_qwen3())
+
+
+def test_a_program_object_naming_no_field_is_refused():
+    cfg = _qwen3()
+    cfg["program"] = {"kv_lora_rank": 512}
+    with pytest.raises(run.BenchError, match="kv_lora_rank"):
+        run.model_config(cfg)
+
+
+def test_a_file_without_head_dim_maps_when_the_program_object_gives_it():
+    cfg = _qwen3()
+    del cfg["head_dim"]
+    with pytest.raises(run.BenchError, match="head_dim"):
+        run.model_config(cfg)
+    cfg["program"] = {"head_dim": 192}
+    got = run.model_config(cfg)
+    assert got.head_dim == 192
+    assert dataclasses.replace(got, head_dim=128) == run.model_config(_qwen3())
+
+
+@pytest.mark.parametrize("key", sorted(run.HF_KEYS))
+def test_a_key_left_out_without_its_program_field_is_refused(key):
+    """A published key missing from the file never falls back to the
+    registry's value unnoticed: the program object has to name its
+    field, and another field there does not stand in for it."""
+    cfg = _qwen3()
+    del cfg[key]
+    cfg["program"] = {"window": 1024}
+    with pytest.raises(run.BenchError, match=key):
+        run.model_config(cfg)
+    cfg["program"][run.HF_KEYS[key]] = _qwen3()[key]
+    assert run.model_config(cfg) == dataclasses.replace(
+        run.model_config(_qwen3()), window=1024)
+
+
+# sha256 of every leaf's path, dtype and bytes, made by make_weights from
+# seed 2**33 + 5 at smoke size on one device, as the parent of the mesh
+# path made them
+ONE_DEVICE_WEIGHTS = {
+    "qwen3-8b": "efbc9aab3eb561e2c6cf687dc8e23d307af37f7120f51246a52a86289457323d",
+    "qwen2-7b": "6940e4a6a05f1b9b6dfc3ebb448a435e45ec1d921467d7120386458ae40796e9",
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_DEVICE_WEIGHTS))
+def test_one_device_weights_are_unchanged(name):
+    cfg = run.load_json(ROOT / "bench" / "configs" / f"{name}.json")
+    assert cfg["mesh"] == [1, 1]
+    cfg.update({"hidden_size": 128, "intermediate_size": 256,
+                "num_attention_heads": 4, "num_key_value_heads": 2,
+                "head_dim": 32, "num_hidden_layers": 2, "vocab_size": 512})
+    ref = check.reference_module(cfg)
+    params = run.make_weights(ref, ref.dims(cfg), run.model_config(cfg),
+                              2 ** 33 + 5, cfg["mesh"])
+    h = hashlib.sha256()
+    for path, x in jax.tree_util.tree_flatten_with_path(params)[0]:
+        # not under a one-device mesh: that made the engine compile
+        # again inside the measured window on the chip
+        assert isinstance(x.sharding, jax.sharding.SingleDeviceSharding)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(x.dtype).encode())
+        h.update(np.asarray(x).tobytes())
+    assert h.hexdigest() == ONE_DEVICE_WEIGHTS[name]
+
+
+class _StatsDevice:
+    def __init__(self, stats):
+        self.stats = stats
+
+    def memory_stats(self):
+        return self.stats
+
+
+def test_the_peak_counts_the_memory_reserved_for_programs():
+    # a TPU holds a program's temporaries as reserved bytes, apart from
+    # the arrays that peak_bytes_in_use counts
+    fullest = {"peak_bytes_in_use": 8 << 30, "peak_bytes_reserved": 5 << 30}
+    devices = [_StatsDevice({"peak_bytes_in_use": 9 << 30}),
+               _StatsDevice(fullest), _StatsDevice(None)]
+    assert run.peak_bytes(devices) == 13 << 30
+    assert run.peak_bytes([_StatsDevice(None), _StatsDevice({})]) is None
 
 
 @pytest.mark.parametrize("mix", ["decode-backlog", "longprompt-chat"])

@@ -10,7 +10,7 @@ import pathlib
 
 import pytest
 
-from bench import measured, trace_reduce as tr
+from bench import measured, run, trace_reduce as tr
 
 FIXTURE = (pathlib.Path(__file__).parent / "data"
            / "decode_backlog_v5e.xplane.pb")
@@ -93,6 +93,26 @@ def test_collective_share_counts_only_the_exposed_part():
     assert s.busy_s == pytest.approx((7.0 + 2.0) / 2)
     # chip 0's idle gaps (6-8, 9-10) fall inside the step span
     assert dict(s.idle_gaps) == {"bench.step": pytest.approx(3.0)}
+
+
+def test_collective_share_reads_the_exposed_part_of_the_window():
+    ops = {0: [_ev("fusion.1", 0.0, 4.0), _ev("all-reduce.2", 3.0, 6.0)],
+           1: [_ev("all-reduce.1", 0.0, 1.0), _ev("fusion.2", 1.0, 9.0)]}
+    host = {"main": [_ev(tr.TRACED_SPAN, 0.0, 10.0)]}
+    s = tr.summarize(tr.Trace(ops, {}, host), 0.0, 10.0)
+    read = run.reader("collective_share.tp4")
+    run_ = measured.Run({}, {}, {}, {}, {}, 2, [], 0.0, 10.0, 10.0, 1.0)
+    assert read(run_) is None                      # no trace
+    run_.trace = s
+    # chip 0: 2 s exposed (4-6), chip 1: 1 s; mean 1.5 s of 10
+    assert read(run_) == pytest.approx(15.0)
+
+
+def test_collective_share_is_none_on_one_chip(chip):
+    _, s = chip
+    run_ = measured.Run({}, {}, {}, {}, {}, 1, [], 0.0, 1.0, 1.0, 1.0)
+    run_.trace = s
+    assert run.reader("collective_share.tp4")(run_) is None
 
 
 def test_nested_operations_count_once():
